@@ -16,20 +16,21 @@ if os.environ.get("XLA_FLAGS", "").find("device_count") < 0:
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.collectives.schedule import DCN, best_broadcast
 from repro.collectives.tree_collectives import (snow_allreduce,
                                                 snow_broadcast,
                                                 two_tree_broadcast)
-from repro.compat import shard_map
 
-mesh = jax.make_mesh((8,), ("hosts",))
+# Auto axes: plain indexing of the shard_map output (``out[root]``)
+# needs no out_sharding, as it would on an Explicit mesh
+mesh = jax.make_mesh((8,), ("hosts",), axis_types=(AxisType.Auto,))
 x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
 
 
 def run(fn):
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("hosts"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("hosts"),
                        out_specs=P("hosts"), check_vma=False)
     def body(xx):
         return fn(xx[0])[None]

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 from .topology import (broadcast_schedule, reduce_schedule,
                        two_tree_schedules)
 
@@ -97,7 +95,7 @@ def _spmd(fn, mesh: Mesh, axis_name: str, **kw):
     # the tree schedule moves it; check_vma off because replication of
     # the output is a property of the schedule, not provable by types.
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(), out_specs=P(), check_vma=False)
     def run(x):
         return fn(x, axis_name, axis_size=mesh.shape[axis_name], **kw)

@@ -412,25 +412,30 @@ def _stack_epochs(epochs) -> Tuple[dict, int, int, int]:
     return st, q, maxp, height
 
 
+def trace_ldt_args(epochs, trace, seeds: Sequence[int]) -> Tuple[tuple, dict]:
+    """The host arrays and static arguments of :func:`_trace_ldt` for
+    one trace: ``(seeds, stacked epochs, fixed mask)`` and
+    ``(q, height, maxp, n_slots, m_total)``."""
+    st, q, maxp, height = _stack_epochs(epochs)
+    for i, ep in enumerate(epochs):
+        sel = (ep.members < trace.n) & (ep.members != trace.src)
+        st["sel"][i, :ep.members.shape[0]] = sel
+    args = (np.asarray(list(seeds), dtype=np.uint32), st,
+            trace.all_ids() < trace.n)
+    static = dict(q=q, height=height, maxp=maxp,
+                  n_slots=int(st["slot"].max()) + 1,
+                  m_total=len(trace.msg_times))
+    return args, static
+
+
 def trace_ldt_device(epochs, trace, seeds: Sequence[int]) -> np.ndarray:
     """Per-seed mean LDT over the paper's fixed subset for a whole churn
     trace — every seed × epoch × message in one fused dispatch.  The
     delay-independent metrics (reliability, RMR) are the caller's job
     (``trace_sweep`` computes them once on the host); only the LDT
     reduction needs the delays."""
-    st, q, maxp, height = _stack_epochs(epochs)
-    for i, ep in enumerate(epochs):
-        sel = (ep.members < trace.n) & (ep.members != trace.src)
-        st["sel"][i, :ep.members.shape[0]] = sel
-    bank_members = trace.all_ids()
-    n_slots = int(st["slot"].max()) + 1
-    out = _trace_ldt(
-        jnp.asarray(np.asarray(list(seeds), dtype=np.uint32)),
-        {k: jnp.asarray(v) for k, v in st.items()},
-        jnp.asarray(bank_members < trace.n),
-        q=q, height=height, maxp=maxp, n_slots=n_slots,
-        m_total=len(trace.msg_times))
-    return np.asarray(out)
+    args, static = trace_ldt_args(epochs, trace, seeds)
+    return np.asarray(_trace_ldt(*jax.tree.map(jnp.asarray, args), **static))
 
 
 # ------------------------------------------------------------------ #

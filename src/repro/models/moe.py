@@ -19,7 +19,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from .config import ModelConfig
 from .layers import norm_def, rmsnorm
 from .shardings import ParamDef, constrain
@@ -189,7 +188,7 @@ def moe_apply_shard_map(cfg: ModelConfig, p, x: jax.Array, mesh, rules
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(bt_spec, None, None),          # x: batch-sharded, model-replicated
                   P(),                              # router replicated
                   P("model", None, data_axes),      # w_gate (E, d, f)

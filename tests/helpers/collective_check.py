@@ -4,20 +4,21 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.collectives.tree_collectives import (snow_allreduce,
                                                 snow_broadcast,
                                                 snow_reduce,
                                                 two_tree_broadcast)
-from repro.compat import shard_map
 
-mesh = jax.make_mesh((8,), ("x",))
+# Auto axes: plain indexing of the shard_map output (``out[root]``)
+# needs no out_sharding, as it would on an Explicit mesh
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 x = jnp.arange(8 * 6, dtype=jnp.float32).reshape(8, 6)
 
 
 def run(fn):
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("x"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("x"),
                        out_specs=P("x"), check_vma=False)
     def body(xx):
         return fn(xx[0])[None]

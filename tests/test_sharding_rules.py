@@ -1,7 +1,7 @@
 """Logical-axis → mesh assignment: greedy, divisibility-checked."""
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.models.shardings import rules_for, spec_for
 
@@ -14,8 +14,7 @@ def mesh():
 def spec(axes, shape, fsdp=False, mesh_shape=(16, 16), names=("data", "model")):
     # use abstract mesh-like object: construct with real devices is fine for 1x1;
     # for 16x16 math we only need shape/axis_names
-    from repro.compat import abstract_mesh
-    am = abstract_mesh(mesh_shape, names)
+    am = AbstractMesh(mesh_shape, names)
     return spec_for(axes, shape, am, rules_for(fsdp))
 
 
@@ -43,8 +42,7 @@ def test_fsdp_spreads_over_both_axes():
 
 
 def test_batch_takes_pod_and_data():
-    from repro.compat import abstract_mesh
-    am = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    am = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     s = spec_for(("batch", None, "embed"), (256, 4096, 1024), am,
                  rules_for(False))
     assert s[0] == ("pod", "data")
