@@ -42,6 +42,7 @@ from jax import lax
 from ..kernels.tree_sweep import fwd_at_parent, level_sweep_xla
 from .planner import SECONDARY, TreePlan
 from .sim import LatencyModel
+from .spans import span
 
 # draw tags — the last fold_in of the key chain picks the variate
 _TAG_FWD, _TAG_LINK, _TAG_STRAGGLER, _TAG_LOSS = 0, 1, 2, 3
@@ -66,6 +67,11 @@ def _plan_meta(plans: Sequence[TreePlan]) -> Tuple[Tuple[int, int, int], ...]:
 # ------------------------------------------------------------------ #
 # Counter-based delay generation                                      #
 # ------------------------------------------------------------------ #
+# Each stage of a device program carries a named scope in its ops'
+# metadata, so a profiler trace can split a program's time by stage:
+# ``delay_planes`` (here), ``epoch_gather`` (a trace epoch's window),
+# ``level_sweep`` (kernels/tree_sweep.py) and ``ldt_reduce``.
+@jax.named_scope("delay_planes")
 def _straggler_mask(base, fixed_mask, frac=STRAGGLER_FRAC):
     """(n,) bool — per-node Bernoulli(``frac``) over the fixed ids.  The
     host oracle draws an *exact-count* sample (``straggler_sample``);
@@ -76,6 +82,7 @@ def _straggler_mask(base, fixed_mask, frac=STRAGGLER_FRAC):
     return (u < frac) & fixed_mask
 
 
+@jax.named_scope("delay_planes")
 def _fwd_link_planes(base, slot, m, n, strag):
     """``(m, n)`` forwarding/link delay planes for one tree slot,
     regenerated from counters: key = ``(seed → slot → tag)``, counter =
@@ -91,6 +98,7 @@ def _fwd_link_planes(base, slot, m, n, strag):
     return fwd, link
 
 
+@jax.named_scope("delay_planes")
 def _loss_planes(base, slot, m, n, rate, timeout_s, max_attempts):
     """(m, n) retransmit-extra delays and lost masks — the device twin
     of ``LossModel.edge_faults``.  Same protocol (Bernoulli per attempt,
@@ -131,11 +139,12 @@ def _stable_stats(seeds, parents, depths, rate_s, straggler_frac, *,
                                 t0.astype(fwd.dtype),
                                 root=root, height=height)
             total = t if total is None else jnp.fmin(total, t)
-        valid = (ids != root0)[None, :] & ~jnp.isnan(total)
-        sub = total - t0[:, None].astype(total.dtype)
-        ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-        rel = valid.sum(axis=1) / (n - 1)
-        return ldt.mean(), rel.mean()
+        with jax.named_scope("ldt_reduce"):
+            valid = (ids != root0)[None, :] & ~jnp.isnan(total)
+            sub = total - t0[:, None].astype(total.dtype)
+            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
+            rel = valid.sum(axis=1) / (n - 1)
+            return ldt.mean(), rel.mean()
 
     return jax.vmap(one)(seeds)
 
@@ -165,11 +174,12 @@ def _stable_stats_hier(seeds, parents, depths, scales, rate_s,
                                 t0.astype(fwd.dtype),
                                 root=root, height=height)
             total = t if total is None else jnp.fmin(total, t)
-        valid = (ids != root0)[None, :] & ~jnp.isnan(total)
-        sub = total - t0[:, None].astype(total.dtype)
-        ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-        rel = valid.sum(axis=1) / (n - 1)
-        return ldt.mean(), rel.mean()
+        with jax.named_scope("ldt_reduce"):
+            valid = (ids != root0)[None, :] & ~jnp.isnan(total)
+            sub = total - t0[:, None].astype(total.dtype)
+            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
+            rel = valid.sum(axis=1) / (n - 1)
+            return ldt.mean(), rel.mean()
 
     return jax.vmap(one)(seeds)
 
@@ -189,25 +199,32 @@ def stable_stats_device(plans: Sequence[TreePlan], seeds: Sequence[int],
     (``hier.scale_plane``, computed host-side — integer coordinate
     hashing — and fused into the device program as one broadcast
     multiply after the threefry link generation)."""
-    args = (
-        jnp.asarray(np.asarray(list(seeds), dtype=np.uint32)),
-        tuple(jnp.asarray(np.asarray(p.parent, dtype=np.int32))
-              for p in plans),
-        tuple(jnp.asarray(np.asarray(p.depth, dtype=np.int32))
-              for p in plans))
-    kw = dict(meta=_plan_meta(plans), n_messages=int(n_messages),
-              n_fixed=int(np.asarray(plans[0].parent).shape[0]))
-    if hier is None:
-        ldt, rel = _stable_stats(
-            *args, jnp.asarray(float(rate_s)),
-            jnp.asarray(float(straggler_frac)), **kw)
-    else:
-        scales = tuple(jnp.asarray(hier.scale_plane(p).astype(np.float32))
-                       for p in plans)
-        ldt, rel = _stable_stats_hier(
-            *args, scales, jnp.asarray(float(rate_s)),
-            jnp.asarray(float(straggler_frac)), **kw)
-    return np.asarray(ldt), np.asarray(rel)
+    with span("snow.device.pack"):
+        host = [np.asarray(list(seeds), dtype=np.uint32),
+                tuple(np.asarray(p.parent, dtype=np.int32) for p in plans),
+                tuple(np.asarray(p.depth, dtype=np.int32) for p in plans)]
+        if hier is not None:
+            host.append(tuple(hier.scale_plane(p).astype(np.float32)
+                              for p in plans))
+        host += [float(rate_s), float(straggler_frac)]
+        kw = dict(meta=_plan_meta(plans), n_messages=int(n_messages),
+                  n_fixed=int(host[1][0].shape[0]))
+    program = _stable_stats if hier is None else _stable_stats_hier
+    return _run_program(program, host, kw)
+
+
+def _run_program(program, host, static):
+    """One device program on host arrays, each step a span of its own:
+    upload, dispatch (the jitted call until it returns: a compile on a
+    cache miss shows here) and the pull of the result to the host."""
+    leaves = jax.tree.leaves(host)
+    with span("snow.device.upload",
+              bytes=int(sum(np.asarray(a).nbytes for a in leaves))):
+        args = jax.tree.map(jnp.asarray, host)
+    with span("snow.device.dispatch", program=program.__name__):
+        out = program(*args, **static)
+    with span("snow.device.pull"):
+        return jax.tree.map(np.asarray, out)
 
 
 @functools.partial(jax.jit,
@@ -241,14 +258,15 @@ def _stable_stats_loss(seeds, parents, depths, rate_s, straggler_frac,
             receipts = r.astype(jnp.int32) if receipts is None \
                 else receipts + r
             total = t if total is None else jnp.fmin(total, t)
-        valid = (ids != root0)[None, :] & ~jnp.isnan(total)
-        sub = total - t0[:, None].astype(total.dtype)
-        got = valid.any(axis=1)
-        ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-        ldt_mean = (jnp.where(got, ldt, 0.0).sum()
-                    / jnp.maximum(got.sum(), 1))
-        rel = valid.sum(axis=1) / (n - 1)
-        return ldt_mean, rel.mean(), receipts.sum(axis=1).mean()
+        with jax.named_scope("ldt_reduce"):
+            valid = (ids != root0)[None, :] & ~jnp.isnan(total)
+            sub = total - t0[:, None].astype(total.dtype)
+            got = valid.any(axis=1)
+            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
+            ldt_mean = (jnp.where(got, ldt, 0.0).sum()
+                        / jnp.maximum(got.sum(), 1))
+            rel = valid.sum(axis=1) / (n - 1)
+            return ldt_mean, rel.mean(), receipts.sum(axis=1).mean()
 
     return jax.vmap(one)(seeds)
 
@@ -263,18 +281,16 @@ def stable_stats_device_loss(plans: Sequence[TreePlan],
     message)`` of a stable sweep under §11 device-RNG edge loss.  A
     separate entry point so the lossless :func:`stable_stats_device`
     keeps its pinned outputs and jit cache untouched."""
-    ldt, rel, rec = _stable_stats_loss(
-        jnp.asarray(np.asarray(list(seeds), dtype=np.uint32)),
-        tuple(jnp.asarray(np.asarray(p.parent, dtype=np.int32))
-              for p in plans),
-        tuple(jnp.asarray(np.asarray(p.depth, dtype=np.int32))
-              for p in plans),
-        jnp.asarray(float(rate_s)), jnp.asarray(float(straggler_frac)),
-        jnp.asarray(float(loss.rate)), jnp.asarray(float(loss.timeout_s)),
-        meta=_plan_meta(plans), n_messages=int(n_messages),
-        n_fixed=int(np.asarray(plans[0].parent).shape[0]),
-        max_attempts=int(loss.max_attempts))
-    return np.asarray(ldt), np.asarray(rel), np.asarray(rec)
+    with span("snow.device.pack"):
+        host = (np.asarray(list(seeds), dtype=np.uint32),
+                tuple(np.asarray(p.parent, dtype=np.int32) for p in plans),
+                tuple(np.asarray(p.depth, dtype=np.int32) for p in plans),
+                float(rate_s), float(straggler_frac), float(loss.rate),
+                float(loss.timeout_s))
+        kw = dict(meta=_plan_meta(plans), n_messages=int(n_messages),
+                  n_fixed=int(host[1][0].shape[0]),
+                  max_attempts=int(loss.max_attempts))
+    return _run_program(_stable_stats_loss, host, kw)
 
 
 @functools.partial(jax.jit,
@@ -337,8 +353,10 @@ def _trace_ldt(seeds, st, fixed_mask, *, q, height, maxp, n_slots,
         strag = _straggler_mask(base, fixed_mask)
         planes = [_fwd_link_planes(base, s, m_total, n_bank, strag)
                   for s in range(n_slots)]
-        fwd_all = jnp.stack([p[0] for p in planes])   # (S, M, n_bank)
-        link_all = jnp.stack([p[1] for p in planes])
+        # XLA fuses the draws into the stacking: scoped with them
+        with jax.named_scope("delay_planes"):
+            fwd_all = jnp.stack([p[0] for p in planes])   # (S, M, n_bank)
+            link_all = jnp.stack([p[1] for p in planes])
 
         def ep_fn(e):
             cols = jnp.clip(e["col0"] + jnp.arange(q, dtype=jnp.int32),
@@ -347,27 +365,30 @@ def _trace_ldt(seeds, st, fixed_mask, *, q, height, maxp, n_slots,
             total = jnp.full((q, p0), jnp.nan, dtype=jnp.float32)
             for p in range(maxp):
                 sl = e["slot"][p]
-                fwd = jnp.take(jnp.take(fwd_all, sl, axis=0)[cols],
-                               e["rows"], axis=-1)        # (q, P)
-                link = jnp.take(jnp.take(link_all, sl, axis=0)[cols],
-                                e["rows"], axis=-1)
+                with jax.named_scope("epoch_gather"):
+                    fwd = jnp.take(jnp.take(fwd_all, sl, axis=0)[cols],
+                                   e["rows"], axis=-1)        # (q, P)
+                    link = jnp.take(jnp.take(link_all, sl, axis=0)[cols],
+                                    e["rows"], axis=-1)
                 parent = e["parent"][p]
-                fp = jnp.where(parent == e["root"], 0.0,
-                               jnp.take(fwd, parent, axis=-1))
+                fp = fwd_at_parent(parent, fwd, e["root"])
                 t = level_sweep_xla(parent, e["depth"][p], fp, link,
                                     e["times"].astype(fwd.dtype),
                                     root=e["root"], height=height)
                 total = jnp.fmin(total, jnp.where(e["mask"][p], t,
                                                   jnp.nan))
-            sub = total - e["times"][:, None].astype(total.dtype)
-            valid = e["sel"][None, :] & ~jnp.isnan(total)
-            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-            ok = e["msgmask"] & valid.any(axis=1)
-            return jnp.where(ok, ldt, 0.0).sum(), ok.sum()
+            with jax.named_scope("ldt_reduce"):
+                sub = total - e["times"][:, None].astype(total.dtype)
+                valid = e["sel"][None, :] & ~jnp.isnan(total)
+                ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
+                ok = e["msgmask"] & valid.any(axis=1)
+                return jnp.where(ok, ldt, 0.0).sum(), ok.sum()
 
         sums, cnts = lax.map(ep_fn, st)
-        c = cnts.sum()
-        return jnp.where(c > 0, sums.sum() / jnp.maximum(c, 1), jnp.nan)
+        with jax.named_scope("ldt_reduce"):
+            c = cnts.sum()
+            return jnp.where(c > 0, sums.sum() / jnp.maximum(c, 1),
+                             jnp.nan)
 
     return jax.vmap(one)(seeds)
 
@@ -434,8 +455,9 @@ def trace_ldt_device(epochs, trace, seeds: Sequence[int]) -> np.ndarray:
     delay-independent metrics (reliability, RMR) are the caller's job
     (``trace_sweep`` computes them once on the host); only the LDT
     reduction needs the delays."""
-    args, static = trace_ldt_args(epochs, trace, seeds)
-    return np.asarray(_trace_ldt(*jax.tree.map(jnp.asarray, args), **static))
+    with span("snow.device.pack"):
+        args, static = trace_ldt_args(epochs, trace, seeds)
+    return _run_program(_trace_ldt, args, static)
 
 
 # ------------------------------------------------------------------ #

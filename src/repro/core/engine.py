@@ -78,6 +78,7 @@ from .messages import Data
 from .planner import (PRIMARY, SECONDARY, TreePlan, depth_levels,
                       plan_broadcast, plan_colored, plan_delta_chain)
 from .sim import LatencyModel, Metrics, Sim, straggler_sample
+from .spans import span
 from .specs import NetworkSpec, RunSpec, resolve_specs
 from .topology import TIER_NAMES, HierarchicalLatency
 
@@ -808,14 +809,14 @@ def stable_sweep(protocol: str, n: int, k: int, seeds: Sequence[int],
     Row schema: ``ldt`` (s), ``rmr`` / ``rmr_redundant`` (bytes/node per
     message — a uniform stable view reaches every non-root node on every
     tree, so redundancy is exactly one frame per extra tree),
-    ``reliability``, ``wall_s``/``plan_s`` timings (the one-time plan
-    compile is attributed to the FIRST row only — summing ``plan_s``
-    over rows equals the cost paid once), and — when ``control`` is
+    ``reliability``, ``wall_s``/``plan_s`` timings (each seed's share of
+    the ``snow.sweep`` span; the one-time plan compile, the
+    ``snow.plan.trees`` span, is attributed to the FIRST row only —
+    summing ``plan_s`` over rows equals the cost paid once), and — when
+    ``control`` is
     given — the §9 per-category control totals under ``control_B`` plus
     the run duration ``duration_s`` the rates were integrated over.
     """
-    import time
-
     net, run = resolve_specs(net, run, caller="stable_sweep",
                              engine=engine, backend=backend,
                              control=control, loss=loss, repair=repair)
@@ -825,16 +826,18 @@ def stable_sweep(protocol: str, n: int, k: int, seeds: Sequence[int],
     ring = net.ring(np.arange(n))
     plan_s = 0.0
     if plans is None:
-        tp = time.time()
-        plans = stable_plans(protocol, np.arange(n), 0, k, ring=ring)
-        plan_s = time.time() - tp
+        with span("snow.plan.trees", epochs=1, full=1) as sp:
+            plans = stable_plans(protocol, np.arange(n), 0, k, ring=ring)
+        plan_s = sp.seconds
     nbytes = plan_bytes(plans, payload)
     frame = Data(0, 0, None, None, payload).size
     t0 = np.arange(n_messages, dtype=np.float64) * rate_s
     duration = n_messages * rate_s
-    ctl = snow_stable_control(
-        n, duration, _repair_control_params(control, repair)) \
-        if control else None
+    ctl = None
+    if control:
+        with span("snow.control"):
+            ctl = snow_stable_control(
+                n, duration, _repair_control_params(control, repair))
     seeds = list(seeds)
     lossy = net.loss_on
     tier_B = None
@@ -857,44 +860,40 @@ def stable_sweep(protocol: str, n: int, k: int, seeds: Sequence[int],
             protocol, n, k, seeds, n_messages, rate_s, backend, plans,
             payload, engine, loss if lossy else None, repair,
             nbytes, frame, t0, duration, ctl, plan_s, hier=hier)
-    if engine == "device":
-        from .device_sweep import stable_stats_device
+    with span("snow.sweep", engine=engine) as sw:
+        if engine == "device":
+            from .device_sweep import stable_stats_device
 
-        tw = time.time()
-        ldt_mean, rel_mean = stable_stats_device(
-            plans, seeds, n_messages, rate_s, hier=hier)
-        wall = time.time() - tw
-        stats = [(float(ldt_mean[i]), float(rel_mean[i]),
-                  wall / max(1, len(seeds))) for i in range(len(seeds))]
-    else:
-        assert engine == "host", f"engine must be host|device, not {engine!r}"
-        ridx = plans[0].root
-        stats = []
-        for seed in seeds:
-            tw = time.time()
-            bank = bank_for_stable(seed, n, protocol, n_messages,
-                                   latency=net.latency_model())
-            times = broadcast_times(plans, bank, n_messages, rate_s, backend,
-                                    hier=hier)
-            # the root originates, never receives (ring index 0 unless a
-            # locality ring placed node 0 elsewhere)
-            rel = times[:, 1:] if ridx == 0 \
-                else times[:, np.arange(times.shape[1]) != ridx]
-            ldt = np.nanmax(rel - t0[:, None], axis=1)
-            delivered = np.count_nonzero(~np.isnan(rel), axis=1)
-            stats.append((float(ldt.mean()),
-                          float(delivered.mean()) / (n - 1),
-                          time.time() - tw))
+            stats = list(zip(*stable_stats_device(
+                plans, seeds, n_messages, rate_s, hier=hier)))
+        else:
+            assert engine == "host", \
+                f"engine must be host|device, not {engine!r}"
+            ridx = plans[0].root
+            stats = []
+            for seed in seeds:
+                bank = bank_for_stable(seed, n, protocol, n_messages,
+                                       latency=net.latency_model())
+                times = broadcast_times(plans, bank, n_messages, rate_s,
+                                        backend, hier=hier)
+                # the root originates, never receives (ring index 0
+                # unless a locality ring placed node 0 elsewhere)
+                rel = times[:, 1:] if ridx == 0 \
+                    else times[:, np.arange(times.shape[1]) != ridx]
+                ldt = np.nanmax(rel - t0[:, None], axis=1)
+                delivered = np.count_nonzero(~np.isnan(rel), axis=1)
+                stats.append((ldt.mean(), delivered.mean() / (n - 1)))
+    wall = sw.seconds / max(1, len(seeds))
     rows = []
-    for i, (seed, (ldt_i, rel_i, wall_i)) in enumerate(zip(seeds, stats)):
+    for i, (seed, (ldt_i, rel_i)) in enumerate(zip(seeds, stats)):
         row = {
             "seed": int(seed), "n": n, "k": k,
-            "ldt": ldt_i,
+            "ldt": float(ldt_i),
             "rmr": nbytes / (n - 1),
             "rmr_redundant": float(frame * (len(plans) - 1)),
-            "reliability": rel_i,
+            "reliability": float(rel_i),
             "n_messages": n_messages,
-            "wall_s": wall_i,
+            "wall_s": wall,
             "plan_s": plan_s if i == 0 else 0.0,
             "engine": engine,
         }
@@ -920,8 +919,6 @@ def _stable_sweep_faulty(protocol, n, k, seeds, n_messages, rate_s,
     repair on, the closed-form ``repair_B``.  ``engine="device"``
     supports loss (threefry masks, statistically pinned) but not
     repair (the repair fill needs the full times plane on the host)."""
-    import time
-
     def _finish(seed, i, ldt, rel, rmr, red, wall, extra):
         row = {
             "seed": int(seed), "n": n, "k": k,
@@ -953,10 +950,10 @@ def _stable_sweep_faulty(protocol, n, k, seeds, n_messages, rate_s,
                 "device loss kernel draws flat-rate masks only")
         from .device_sweep import stable_stats_device_loss
 
-        tw = time.time()
-        ldt_m, rel_m, rec_m = stable_stats_device_loss(
-            plans, seeds, n_messages, rate_s, loss=loss)
-        wall = (time.time() - tw) / max(1, len(seeds))
+        with span("snow.sweep", engine=engine) as sw:
+            ldt_m, rel_m, rec_m = stable_stats_device_loss(
+                plans, seeds, n_messages, rate_s, loss=loss)
+        wall = sw.seconds / max(1, len(seeds))
         rows = []
         for i, seed in enumerate(seeds):
             delivered = float(rel_m[i]) * (n - 1)
@@ -972,49 +969,53 @@ def _stable_sweep_faulty(protocol, n, k, seeds, n_messages, rate_s,
 
     assert engine == "host", f"engine must be host|device, not {engine!r}"
     members = np.arange(n)
-    rows = []
-    for i, seed in enumerate(seeds):
-        tw = time.time()
-        bank = bank_for_stable(
-            seed, n, protocol, n_messages,
-            latency=None if hier is None else hier.latency_model())
-        times, rec = broadcast_times(plans, bank, n_messages, rate_s,
-                                     backend, loss=loss,
-                                     with_receipts=True, hier=hier)
-        repaired = None
-        if repair is not None:
-            times, repaired = _repair_fill(times, t0, members, None,
-                                           n, 0, repair)
-            miss = repaired
-        else:
-            miss = np.isnan(times)
-            miss[:, 0] = False           # the root always holds the payload
-        sub = times[:, 1:] - t0[:, None]
-        cnt = (~np.isnan(sub)).sum(axis=1)
-        got = cnt > 0
-        ldt = np.full(n_messages, np.nan)
-        if got.any():
-            ldt[got] = np.nanmax(sub[got], axis=1)
-        rec_sub = rec[:, 1:].sum(axis=1)
-        push_cnt = cnt if repaired is None \
-            else cnt - repaired[:, 1:].sum(axis=1)
-        n_missed = int(miss.sum())
-        extra = {
-            "n_repaired": 0 if repaired is None else int(repaired.sum()),
-            "rebroadcast_B": float(nbytes * int(miss.any(axis=1).sum())),
-        }
-        if repair is not None:
-            extra["repair_B"] = float(
-                repair_digest_epoch_bytes(n, 0, duration,
-                                          repair.interval_s)
-                + repair_fetch_bytes(n_missed, payload))
-        rows.append(_finish(
-            seed, i, float(np.nanmean(ldt)),
-            float(cnt.mean()) / (n - 1),
-            frame * float(rec_sub.mean()) / (n - 1),
-            frame * float((rec_sub - push_cnt).mean()) / (n - 1),
-            time.time() - tw, extra))
-    return rows
+    stats = []
+    with span("snow.sweep", engine=engine) as sw:
+        for seed in seeds:
+            bank = bank_for_stable(
+                seed, n, protocol, n_messages,
+                latency=None if hier is None else hier.latency_model())
+            times, rec = broadcast_times(plans, bank, n_messages, rate_s,
+                                         backend, loss=loss,
+                                         with_receipts=True, hier=hier)
+            repaired = None
+            if repair is not None:
+                times, repaired = _repair_fill(times, t0, members, None,
+                                               n, 0, repair)
+                miss = repaired
+            else:
+                miss = np.isnan(times)
+                miss[:, 0] = False       # the root always holds the payload
+            sub = times[:, 1:] - t0[:, None]
+            cnt = (~np.isnan(sub)).sum(axis=1)
+            got = cnt > 0
+            ldt = np.full(n_messages, np.nan)
+            if got.any():
+                ldt[got] = np.nanmax(sub[got], axis=1)
+            rec_sub = rec[:, 1:].sum(axis=1)
+            push_cnt = cnt if repaired is None \
+                else cnt - repaired[:, 1:].sum(axis=1)
+            n_missed = int(miss.sum())
+            extra = {
+                "n_repaired": 0 if repaired is None
+                else int(repaired.sum()),
+                "rebroadcast_B": float(
+                    nbytes * int(miss.any(axis=1).sum())),
+            }
+            if repair is not None:
+                extra["repair_B"] = float(
+                    repair_digest_epoch_bytes(n, 0, duration,
+                                              repair.interval_s)
+                    + repair_fetch_bytes(n_missed, payload))
+            stats.append((float(np.nanmean(ldt)),
+                          float(cnt.mean()) / (n - 1),
+                          frame * float(rec_sub.mean()) / (n - 1),
+                          frame * float((rec_sub - push_cnt).mean())
+                          / (n - 1), extra))
+    wall = sw.seconds / max(1, len(seeds))
+    return [_finish(seed, i, ldt, rel, rmr, red, wall, extra)
+            for i, (seed, (ldt, rel, rmr, red, extra))
+            in enumerate(zip(seeds, stats))]
 
 
 # ------------------------------------------------------------------ #
@@ -1035,6 +1036,7 @@ class _EpochPlan:
     receipts: np.ndarray = None      #: (n_e,) frame receipts per member
     frame: int = 0                   #: wire size of one DATA frame
     crashed_mask: Optional[np.ndarray] = None  #: (n_e,) bool; None=none
+    full: bool = False               #: planned from scratch, not by delta
 
     @property
     def count(self) -> int:
@@ -1093,6 +1095,7 @@ def compile_trace(protocol: str, trace: ChurnTrace, k: int,
             and members[np.searchsorted(members, trace.src)] == trace.src, \
             "the broadcast source left or was evicted mid-trace"
         plans = rows = None
+        full = False
         evs = trans.get(ep.first)
         n_memb = 0 if evs is None else sum(e.kind != "crash" for e in evs)
         if prev is not None and evs is not None \
@@ -1112,6 +1115,7 @@ def compile_trace(protocol: str, trace: ChurnTrace, k: int,
         if plans is None:
             plans = stable_plans(protocol, members, trace.src, k)
             rows = np.searchsorted(bank_members, members)
+            full = True
         cmask = np.isin(members, ep.crashed) if ep.crashed.size else None
         reach: List[Optional[np.ndarray]] = []
         receipts = np.zeros(members.shape[0], dtype=np.int64)
@@ -1129,7 +1133,7 @@ def compile_trace(protocol: str, trace: ChurnTrace, k: int,
             first=ep.first, times=ep.times, plans=plans,
             reach=tuple(reach), nbytes=size * int(receipts.sum()),
             src_index=int(np.searchsorted(members, trace.src)),
-            receipts=receipts, frame=size, crashed_mask=cmask))
+            receipts=receipts, frame=size, crashed_mask=cmask, full=full))
         prev = out[-1]
     return out
 
@@ -1632,7 +1636,8 @@ def trace_sweep(protocol: str, trace: ChurnTrace, k: int,
     (seed-independent expected values over the trace) to every row
     under ``control_B``, with the integration window in ``duration_s``.
     The one-time ``plan_s`` compile cost is attributed to the first row
-    only, so summed wall-time reports count it once.
+    only, so summed wall-time reports count it once; ``wall_s`` is each
+    seed's share of the ``snow.sweep`` span (:mod:`repro.core.spans`).
 
     ``loss``/``repair`` run the §11 fault + pull-repair closed forms
     (host engine only — the device path's delay-independent byte/reach
@@ -1644,8 +1649,6 @@ def trace_sweep(protocol: str, trace: ChurnTrace, k: int,
     least one node).  Reliability under repair is over the alive fixed
     subset (crashed members cannot be repaired).
     """
-    import time
-
     net, run = resolve_specs(net, run, caller="trace_sweep",
                              engine=engine, backend=backend,
                              control=control, loss=loss, repair=repair)
@@ -1666,20 +1669,26 @@ def trace_sweep(protocol: str, trace: ChurnTrace, k: int,
         raise ValueError(
             "hierarchical trace sweeps require engine='host': the device "
             "trace kernel generates flat-latency delays only")
-    bank_members = trace.all_ids()
     plan_s = 0.0
     if epochs is None:
-        tp = time.time()
-        epochs = compile_trace(protocol, trace, k, bank_members, payload,
-                               replan=run.replan)
-        plan_s = time.time() - tp
-    ctl = snow_trace_control(
-        trace, params=_repair_control_params(control, repair)) \
-        if control else None
-    spans = trace.epoch_spans()
-    trace_duration = float(spans[-1][1] - spans[0][0]) if spans else 0.0
-    fixed_sel = [(ep.members < trace.n) & (ep.members != trace.src)
-                 for ep in epochs]
+        with span("snow.trace.scan"):
+            bank_members = trace.all_ids()
+        with span("snow.plan.trees") as sp:
+            epochs = compile_trace(protocol, trace, k, bank_members,
+                                   payload, replan=run.replan)
+            sp.set(epochs=len(epochs), full=sum(ep.full for ep in epochs))
+        plan_s = sp.seconds
+    ctl = None
+    if control:
+        with span("snow.control"):
+            ctl = snow_trace_control(
+                trace, params=_repair_control_params(control, repair))
+    with span("snow.trace.scan"):
+        spans = trace.epoch_spans()
+        trace_duration = float(spans[-1][1] - spans[0][0]) if spans \
+            else 0.0
+        fixed_sel = [(ep.members < trace.n) & (ep.members != trace.src)
+                     for ep in epochs]
     seeds = list(seeds)
 
     def _finish(seed, i, ldt, rmr, red, rel, wall, extra=None):
@@ -1711,108 +1720,114 @@ def trace_sweep(protocol: str, trace: ChurnTrace, k: int,
         rmrs: List[float] = []
         rels: List[float] = []
         reds: List[float] = []
-        for ep, sel in zip(epochs, fixed_sel):
-            n_int = int(sel.sum())
-            rec_sub = int(ep.receipts[sel].sum())
-            reached = np.zeros(ep.members.shape[0], dtype=bool)
-            for plan, ok in zip(ep.plans, ep.reach):
-                covered = np.asarray(plan.depth) >= 1
-                reached |= covered if ok is None else (ok & covered)
-            cnt = int(reached[sel].sum())
-            rels.extend([cnt / max(1, n_int)] * ep.count)
-            rmrs.extend([ep.frame * rec_sub / max(1, n_int)] * ep.count)
-            reds.extend([ep.frame * (rec_sub - cnt) / max(1, n_int)]
-                        * ep.count)
-        tw = time.time()
-        ldt_dev = trace_ldt_device(epochs, trace, seeds)
-        wall = (time.time() - tw) / max(1, len(seeds))
-        return [_finish(seed, i, float(ldt_dev[i]), float(np.mean(rmrs)),
-                        float(np.mean(reds)), float(np.mean(rels)), wall)
-                for i, seed in enumerate(seeds)]
+        with span("snow.rows"):
+            for ep, sel in zip(epochs, fixed_sel):
+                n_int = int(sel.sum())
+                rec_sub = int(ep.receipts[sel].sum())
+                reached = np.zeros(ep.members.shape[0], dtype=bool)
+                for plan, ok in zip(ep.plans, ep.reach):
+                    covered = np.asarray(plan.depth) >= 1
+                    reached |= covered if ok is None else (ok & covered)
+                cnt = int(reached[sel].sum())
+                rels.extend([cnt / max(1, n_int)] * ep.count)
+                rmrs.extend([ep.frame * rec_sub / max(1, n_int)] * ep.count)
+                reds.extend([ep.frame * (rec_sub - cnt) / max(1, n_int)]
+                            * ep.count)
+        with span("snow.sweep", engine=engine) as sw:
+            ldt_dev = trace_ldt_device(epochs, trace, seeds)
+        wall = sw.seconds / max(1, len(seeds))
+        with span("snow.rows"):
+            return [_finish(seed, i, float(ldt_dev[i]),
+                            float(np.mean(rmrs)), float(np.mean(reds)),
+                            float(np.mean(rels)), wall)
+                    for i, seed in enumerate(seeds)]
 
     assert engine == "host", f"engine must be host|device, not {engine!r}"
     faulty = lossy or repair is not None
-    rows = []
-    for i, seed in enumerate(seeds):
-        tw = time.time()
-        bank = bank_for_trace(seed, trace, protocol,
-                              latency=net.latency_model())
-        ldts: List[np.ndarray] = []
-        rels: List[np.ndarray] = []
-        rmrs: List[float] = []
-        reds: List[np.ndarray] = []
-        n_repaired = 0
-        n_missed = 0
-        rebroadcast_B = 0.0
-        for ep, sel in zip(epochs, fixed_sel):
-            rec = repaired = None
-            if not faulty:
-                total = _epoch_times(ep, bank, backend, hier=hier)
-            else:
-                total, rec = _epoch_times(ep, bank, backend, loss=loss,
-                                          with_receipts=True, hier=hier)
-                alive = np.ones(ep.members.shape[0], dtype=bool) \
-                    if ep.crashed_mask is None else ~ep.crashed_mask
-                if repair is not None:
-                    m_e = ep.members.shape[0]
-                    c_e = int(np.count_nonzero(~alive))
-                    total, repaired = _repair_fill(
-                        total, ep.times, ep.members, ep.crashed_mask,
-                        m_e, c_e, repair)
-                    miss = repaired
-                    n_repaired += int(repaired.sum())
+    stats = []
+    with span("snow.sweep", engine=engine) as sw:
+        for seed in seeds:
+            bank = bank_for_trace(seed, trace, protocol,
+                                  latency=net.latency_model())
+            ldts: List[np.ndarray] = []
+            rels: List[np.ndarray] = []
+            rmrs: List[float] = []
+            reds: List[np.ndarray] = []
+            n_repaired = 0
+            n_missed = 0
+            rebroadcast_B = 0.0
+            for ep, sel in zip(epochs, fixed_sel):
+                rec = repaired = None
+                if not faulty:
+                    total = _epoch_times(ep, bank, backend, hier=hier)
                 else:
-                    miss = np.isnan(total) & alive[None, :]
-                n_missed += int(miss.sum())
-                rebroadcast_B += float(
-                    ep.nbytes * int(miss.any(axis=1).sum()))
-            # §11 semantics: with repair on, reliability is over the
-            # alive fixed subset — crashed members cannot be repaired
-            basis = sel if (repaired is None or ep.crashed_mask is None) \
-                else (sel & ~ep.crashed_mask)
-            sub = total[:, basis] - ep.times[:, None]
-            cnt = (~np.isnan(sub)).sum(axis=1)
-            ldt = np.full(ep.count, np.nan)
-            got = cnt > 0
-            if got.any():
-                ldt[got] = np.nanmax(sub[got], axis=1)
-            n_int = int(basis.sum())
-            ldts.append(ldt)
-            rels.append(cnt / max(1, n_int))
-            # §5.4 subset semantics: bytes attributed to the metered
-            # population only — frames received BY subset members — not
-            # whole-cluster bytes over the subset denominator
-            if rec is None:
-                rec_sub = int(ep.receipts[sel].sum())
-                rmrs.extend([ep.frame * rec_sub / max(1, n_int)] * ep.count)
-                reds.append(ep.frame * (rec_sub - cnt) / max(1, n_int))
-            else:
-                rec_sub = rec[:, basis].sum(axis=1)
-                push_cnt = cnt if repaired is None \
-                    else cnt - repaired[:, basis].sum(axis=1)
-                rmrs.extend((ep.frame * rec_sub / max(1, n_int)).tolist())
-                reds.append(ep.frame * (rec_sub - push_cnt)
-                            / max(1, n_int))
-        ldt_all = np.concatenate(ldts)
-        rel_all = np.concatenate(rels)
-        red_all = np.concatenate(reds)
-        extra = None
-        if faulty:
-            extra = {"n_repaired": n_repaired,
-                     "rebroadcast_B": rebroadcast_B}
-            if repair is not None:
-                c_mean = float(np.mean(
-                    [0 if ep.crashed_mask is None
-                     else int(ep.crashed_mask.sum()) for ep in epochs]))
-                m_mean = float(np.mean(
-                    [ep.members.shape[0] for ep in epochs]))
-                extra["repair_B"] = float(
-                    repair_digest_epoch_bytes(m_mean, c_mean,
-                                              trace_duration,
-                                              repair.interval_s)
-                    + repair_fetch_bytes(n_missed, payload))
-        rows.append(_finish(seed, i, float(np.nanmean(ldt_all)),
-                            float(np.mean(rmrs)), float(red_all.mean()),
-                            float(rel_all.mean()), time.time() - tw,
-                            extra))
-    return rows
+                    total, rec = _epoch_times(ep, bank, backend, loss=loss,
+                                              with_receipts=True, hier=hier)
+                    alive = np.ones(ep.members.shape[0], dtype=bool) \
+                        if ep.crashed_mask is None else ~ep.crashed_mask
+                    if repair is not None:
+                        m_e = ep.members.shape[0]
+                        c_e = int(np.count_nonzero(~alive))
+                        total, repaired = _repair_fill(
+                            total, ep.times, ep.members, ep.crashed_mask,
+                            m_e, c_e, repair)
+                        miss = repaired
+                        n_repaired += int(repaired.sum())
+                    else:
+                        miss = np.isnan(total) & alive[None, :]
+                    n_missed += int(miss.sum())
+                    rebroadcast_B += float(
+                        ep.nbytes * int(miss.any(axis=1).sum()))
+                # §11 semantics: with repair on, reliability is over the
+                # alive fixed subset — crashed members cannot be repaired
+                basis = sel if (repaired is None or ep.crashed_mask is None) \
+                    else (sel & ~ep.crashed_mask)
+                sub = total[:, basis] - ep.times[:, None]
+                cnt = (~np.isnan(sub)).sum(axis=1)
+                ldt = np.full(ep.count, np.nan)
+                got = cnt > 0
+                if got.any():
+                    ldt[got] = np.nanmax(sub[got], axis=1)
+                n_int = int(basis.sum())
+                ldts.append(ldt)
+                rels.append(cnt / max(1, n_int))
+                # §5.4 subset semantics: bytes attributed to the metered
+                # population only — frames received BY subset members — not
+                # whole-cluster bytes over the subset denominator
+                if rec is None:
+                    rec_sub = int(ep.receipts[sel].sum())
+                    rmrs.extend([ep.frame * rec_sub / max(1, n_int)]
+                                * ep.count)
+                    reds.append(ep.frame * (rec_sub - cnt) / max(1, n_int))
+                else:
+                    rec_sub = rec[:, basis].sum(axis=1)
+                    push_cnt = cnt if repaired is None \
+                        else cnt - repaired[:, basis].sum(axis=1)
+                    rmrs.extend((ep.frame * rec_sub / max(1, n_int)).tolist())
+                    reds.append(ep.frame * (rec_sub - push_cnt)
+                                / max(1, n_int))
+            ldt_all = np.concatenate(ldts)
+            rel_all = np.concatenate(rels)
+            red_all = np.concatenate(reds)
+            extra = None
+            if faulty:
+                extra = {"n_repaired": n_repaired,
+                         "rebroadcast_B": rebroadcast_B}
+                if repair is not None:
+                    c_mean = float(np.mean(
+                        [0 if ep.crashed_mask is None
+                         else int(ep.crashed_mask.sum()) for ep in epochs]))
+                    m_mean = float(np.mean(
+                        [ep.members.shape[0] for ep in epochs]))
+                    extra["repair_B"] = float(
+                        repair_digest_epoch_bytes(m_mean, c_mean,
+                                                  trace_duration,
+                                                  repair.interval_s)
+                        + repair_fetch_bytes(n_missed, payload))
+            stats.append((float(np.nanmean(ldt_all)), float(np.mean(rmrs)),
+                          float(red_all.mean()), float(rel_all.mean()),
+                          extra))
+    wall = sw.seconds / max(1, len(seeds))
+    return [_finish(seed, i, ldt, rmr, red, rel, wall, extra)
+            for i, (seed, (ldt, rmr, red, rel, extra))
+            in enumerate(zip(seeds, stats))]

@@ -65,6 +65,7 @@ from .baselines import gossip_sweep, plumtree_sweep
 from .churn import ChurnTrace, paper_breakdown_trace, paper_churn_trace
 from .control import ControlParams, gossip_control
 from .scenarios import run_breakdown, run_churn, run_stable, summarize
+from .spans import span
 from .specs import NetworkSpec, RunSpec, WorkloadSpec
 
 #: protocols with a closed-form route (any n) vs events-only baselines
@@ -217,38 +218,39 @@ def _reduce(cell: Cell, spec: ExperimentSpec, engine_used: str,
     loop keeps probing through its 15 s drain, the closed forms
     integrate over the span).  Each term is divided by its own window,
     so both engines report the same steady-state rates."""
-    ldts = [s["ldt"] for s in per_seed]
-    rmrs = [s["rmr"] for s in per_seed]
-    reds = [s.get("rmr_redundant", 0.0) for s in per_seed]
-    rels = [s["reliability"] for s in per_seed]
-    row = {
-        "cell": dataclasses.asdict(cell),
-        "engine_used": engine_used,
-        "seeds": list(spec.seeds),
-        "n_messages": spec.n_messages,
-        "ldt_ms": _mean(ldts) * 1000.0,
-        "ldt_ms_ci95": _ci95([v * 1000.0 for v in ldts]),
-        "rmr_B": _mean(rmrs),
-        "redundant_B": _mean(reds),
-        "payload_B": _mean(rmrs) - _mean(reds),
-        "reliability": float(min(rels)) if rels else float("nan"),
-    }
-    if control_totals is not None:
-        if control_window_s is None:
-            control_window_s = data_window_s
-        n = cell.n
-        td = max(data_window_s, 1e-12)
-        tc = max(control_window_s, 1e-12)
-        control_b = float(sum(control_totals.values()))
-        data_bps = _mean(rmrs) * spec.n_messages / td
-        row["control_B"] = {k: float(v) for k, v in
-                            sorted(control_totals.items())}
-        row["data_window_s"] = data_window_s
-        row["control_window_s"] = control_window_s
-        row["control_Bps_node"] = control_b / (n * tc)
-        row["data_Bps_node"] = data_bps
-        row["total_Bps_node"] = data_bps + control_b / (n * tc)
-    return row
+    with span("snow.rows"):
+        ldts = [s["ldt"] for s in per_seed]
+        rmrs = [s["rmr"] for s in per_seed]
+        reds = [s.get("rmr_redundant", 0.0) for s in per_seed]
+        rels = [s["reliability"] for s in per_seed]
+        row = {
+            "cell": dataclasses.asdict(cell),
+            "engine_used": engine_used,
+            "seeds": list(spec.seeds),
+            "n_messages": spec.n_messages,
+            "ldt_ms": _mean(ldts) * 1000.0,
+            "ldt_ms_ci95": _ci95([v * 1000.0 for v in ldts]),
+            "rmr_B": _mean(rmrs),
+            "redundant_B": _mean(reds),
+            "payload_B": _mean(rmrs) - _mean(reds),
+            "reliability": float(min(rels)) if rels else float("nan"),
+        }
+        if control_totals is not None:
+            if control_window_s is None:
+                control_window_s = data_window_s
+            n = cell.n
+            td = max(data_window_s, 1e-12)
+            tc = max(control_window_s, 1e-12)
+            control_b = float(sum(control_totals.values()))
+            data_bps = _mean(rmrs) * spec.n_messages / td
+            row["control_B"] = {k: float(v) for k, v in
+                                sorted(control_totals.items())}
+            row["data_window_s"] = data_window_s
+            row["control_window_s"] = control_window_s
+            row["control_Bps_node"] = control_b / (n * tc)
+            row["data_Bps_node"] = data_bps
+            row["total_Bps_node"] = data_bps + control_b / (n * tc)
+        return row
 
 
 def _events_cell(spec: ExperimentSpec, cell: Cell,
@@ -454,16 +456,26 @@ def _workload_cell(spec: ExperimentSpec, cell: Cell) -> dict:
 def run_cell(spec: ExperimentSpec, cell: Cell) -> dict:
     """Execute one grid cell end to end via :func:`route`; returns the
     reduced row, or a ``{"skipped": reason}`` row for cells no engine
-    can serve — explicit, so reports show the hole."""
+    can serve — explicit, so reports show the hole.  The whole call is
+    the ``snow.query`` span (:mod:`repro.core.spans`)."""
+    with span("snow.query", scene=cell.scene, protocol=cell.protocol,
+              n=cell.n, k=cell.k, seeds=len(spec.seeds)):
+        return _run_cell(spec, cell)
+
+
+def _run_cell(spec: ExperimentSpec, cell: Cell) -> dict:
     if spec.workload is not None:
         if spec.scenes != ("stable",):
             raise ValueError("workload specs drive their own (possibly "
                              "churn-coupled) traffic; use scenes="
                              "('stable',)")
         return _workload_cell(spec, cell)
-    trace = _trace_for(spec, cell)
-    duration = _duration_s(spec, trace)
-    r = route(spec, cell)
+    with span("snow.plan.trace") as sp:
+        trace = _trace_for(spec, cell)
+        r = route(spec, cell)
+        sp.set(events=0 if trace is None else len(trace.events))
+    with span("snow.trace.scan"):
+        duration = _duration_s(spec, trace)
     if r.startswith("skipped:"):
         return {"cell": dataclasses.asdict(cell),
                 "skipped": r.split(":", 1)[1]}

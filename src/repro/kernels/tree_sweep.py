@@ -41,6 +41,7 @@ from jax.experimental import pallas as pl
 DEFAULT_BLOCK_M = 8
 
 
+@jax.named_scope("level_sweep")
 def fwd_at_parent(parent: jax.Array, fwd: jax.Array, root: int) -> jax.Array:
     """``fwd`` gathered at each node's parent, zero where the parent is
     the root (the initiator forwards immediately) — the per-message
@@ -49,6 +50,7 @@ def fwd_at_parent(parent: jax.Array, fwd: jax.Array, root: int) -> jax.Array:
                      jnp.take(fwd, parent, axis=-1))
 
 
+@jax.named_scope("level_sweep")
 def level_sweep_xla(parent: jax.Array, depth: jax.Array, fp: jax.Array,
                     link: jax.Array, t0: jax.Array, *, root: int,
                     height: int) -> jax.Array:

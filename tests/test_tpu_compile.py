@@ -116,3 +116,50 @@ def test_workload_times_compiles_for_one_chip(one_chip):
         _shape(one_chip, (m,), jnp.float32),
         _shape(one_chip, (), jnp.float32), meta=(7, 10, 0)).compile()
     assert _fits_one_chip(compiled) > m * N * 4
+
+
+SCOPED = ("level_sweep", "delay_planes", "epoch_gather", "ldt_reduce")
+
+
+def _entry_fusions(compiled, shape):
+    """``op_name`` of every fusion of the compiled program's entry
+    computation with a result (or a tuple element) of ``shape``."""
+    import re
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    return re.findall(
+        rf"= \(?{re.escape(shape)}[^\n]* fusion\([^\n]*?"
+        rf"op_name=\"([^\"]*)\"", entry)
+
+
+def test_trace_ldt_ops_carry_their_scopes_on_the_chip(one_chip):
+    """On the v5e compiler the named scopes survive fusion: the threefry
+    draws fuse into the stacking of the delay planes, whose op carries
+    ``delay_planes``, and each stage's scope reaches the compiled ops."""
+    n = 20_000
+    trace = paper_churn_trace(n, 20)
+    epochs = compile_trace("snow", trace, 4, trace.all_ids(), 64)
+    args, static = ds.trace_ldt_args(epochs, trace, range(SEEDS))
+    shapes = jax.tree.map(
+        lambda a: _shape(one_chip, a.shape,
+                         jax.dtypes.canonicalize_dtype(a.dtype)), args)
+    compiled = ds._trace_ldt.lower(*shapes, **static).compile()
+    n_bank = shapes[2].shape[0]
+    planes = _entry_fusions(
+        compiled, f"f32[{SEEDS},{static['n_slots']},20,{n_bank}]")
+    assert planes and all("delay_planes" in name for name in planes)
+    text = compiled.as_text()
+    assert all(f"/{scope}/" in text or f"({scope})/" in text
+               for scope in SCOPED)
+
+
+def test_stable_stats_ops_carry_their_scopes_on_the_chip(one_chip):
+    n = 20_000
+    compiled = ds._stable_stats.lower(
+        *_plan_args(one_chip, n, COLORING), meta=COLORING, n_messages=20,
+        n_fixed=n).compile()
+    text = compiled.as_text()
+    for scope in ("level_sweep", "delay_planes", "ldt_reduce"):
+        assert f"/{scope}/" in text or f"({scope})/" in text, scope
