@@ -15,10 +15,11 @@ dominate.  This module removes both:
   load.  Trace epochs gather their ``(columns × bank rows)`` window out
   of the same conceptual plane the stable path generates directly, so
   the two paths draw from one coordinate system.
-* **One dispatch.**  The level sweep (``repro.kernels.tree_sweep``) is
-  ``vmap``-ed across seeds, and for churn traces ``lax.map``-ed across
-  padded epochs inside the seed ``vmap``, so a whole multi-seed cell is
-  a single jitted call.
+* **One dispatch.**  Only the draws are ``vmap``-ed across seeds; each
+  plan's level sweep then runs once over all seeds × messages, laid out
+  node-major (``repro.kernels.tree_sweep.level_sweep_rows``), and churn
+  traces ``lax.map`` over padded epochs with every seed inside each
+  epoch's sweep, so a whole multi-seed cell is a single jitted call.
 
 The numpy :class:`DelayBank` stays the bit-exactness oracle: the device
 path draws from the *same distributions* (uniform 10–200 ms forwarding,
@@ -39,7 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..kernels.tree_sweep import fwd_at_parent, level_sweep_xla
+from ..kernels.tree_sweep import (fwd_at_parent, fwd_at_parent_rows,
+                                  level_sweep_rows, level_sweep_xla)
 from .planner import SECONDARY, TreePlan
 from .sim import LatencyModel
 from .spans import span
@@ -116,72 +118,127 @@ def _loss_planes(base, slot, m, n, rate, timeout_s, max_attempts):
 
 
 # ------------------------------------------------------------------ #
-# Stable scenario: vmap over seeds, one dispatch                      #
+# Node-major layout: all seeds × messages of a plan as one row a node #
 # ------------------------------------------------------------------ #
+#: TPU lane count: a node's row of delivery times is padded to a multiple
+_LANES = 128
+
+
+def _row_width(cols: int) -> int:
+    """Padded row width ``B`` of ``cols`` seed × message columns."""
+    return -(-cols // _LANES) * _LANES
+
+
+@jax.named_scope("level_sweep")
+def _pad_cols(x, width, fill):
+    """Pad the trailing (column) axis of ``x`` to ``width`` with
+    ``fill``; the padding columns are inert (NaN link, NaN ``t0``)."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
+    return jnp.pad(x, pad, constant_values=fill)
+
+
+@jax.named_scope("level_sweep")
+def _to_rows(x, width, fill):
+    """``(S, M, n)`` seed × message planes → node-major ``(n, width)``:
+    column ``s·M + m`` holds message ``m`` of seed ``s``."""
+    s, m, n = x.shape
+    return _pad_cols(jnp.transpose(x, (2, 0, 1)).reshape(n, s * m), width,
+                     fill)
+
+
+def _per_seed(cols, s, m):
+    """``(B,)`` per-column values → ``(S, M)``, padding dropped."""
+    return cols[:s * m].reshape(s, m)
+
+
+def _sum_last(x):
+    """Sum over the last axis, left to right.  Elementwise adds fix the
+    order, which a reduce fused into the reduction over nodes leaves to
+    the compiler: per-seed means are then the same bits however the
+    per-message values were laid out."""
+    return functools.reduce(jnp.add, [x[..., j] for j in range(x.shape[-1])])
+
+
+def _sweep_rows(parent, depth, fwd, link, t0, *, root, height):
+    """(n, B) times of one plan over node-major ``fwd``/``link`` rows."""
+    fp = fwd_at_parent_rows(parent, fwd, root)
+    return level_sweep_rows(parent, depth, fp, link, t0, root=root,
+                            height=height)
+
+
+# ------------------------------------------------------------------ #
+# Stable scenario: draws vmap over seeds, one node-major sweep a plan #
+# ------------------------------------------------------------------ #
+def _stable_sweeps(seeds, parents, depths, rate_s, straggler_frac, *,
+                   meta, n_messages, n_fixed, adjust=None):
+    """Per plan, the ``(n, B)`` delivery times of every seed × message,
+    and the ``(B,)`` start time of each column.  Only the draws are
+    ``vmap``-ed over seeds, on their ``(messages, n)`` counter grid;
+    each plan then runs one node-major sweep over all seeds at once.
+    ``adjust(i, base, slot, link)`` rewrites plan ``i``'s link plane of
+    one seed (tier scales, loss)."""
+    n = parents[0].shape[0]
+    s = seeds.shape[0]
+    width = _row_width(s * n_messages)
+    ids = jnp.arange(n, dtype=jnp.int32)
+    t0 = (jnp.arange(n_messages) * rate_s).astype(jnp.float32)
+
+    def draws(seed):
+        base = jax.random.key(seed)
+        strag = _straggler_mask(base, ids < n_fixed, straggler_frac)
+        out = []
+        for i, (_, _, slot) in enumerate(meta):
+            fwd, link = _fwd_link_planes(base, slot, n_messages, n, strag)
+            if adjust is not None:
+                link = adjust(i, base, slot, link)
+            out.append((fwd, link))
+        return out
+
+    t0_rows = _pad_cols(jnp.tile(t0, s), width, jnp.nan)
+    times = [_sweep_rows(parent, depth, _to_rows(fwd, width, 0.0),
+                         _to_rows(link, width, jnp.nan), t0_rows,
+                         root=root, height=height)
+             for (fwd, link), parent, depth, (root, height, _)
+             in zip(jax.vmap(draws)(seeds), parents, depths, meta)]
+    return times, t0_rows
+
+
+def _stable_reduce(times, t0_rows, root0, s, m):
+    """Per-seed (mean LDT, mean reliability) of the coloring min."""
+    total = functools.reduce(jnp.fmin, times)
+    with jax.named_scope("ldt_reduce"):
+        n = total.shape[0]
+        valid = ((jnp.arange(n, dtype=jnp.int32) != root0)[:, None]
+                 & ~jnp.isnan(total))
+        ldt = jnp.max(jnp.where(valid, total - t0_rows, -jnp.inf), axis=0)
+        rel = valid.sum(axis=0) / (n - 1)
+        return (_sum_last(_per_seed(ldt, s, m)) / m,
+                _sum_last(_per_seed(rel, s, m)) / m)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("meta", "n_messages", "n_fixed"))
 def _stable_stats(seeds, parents, depths, rate_s, straggler_frac, *,
                   meta, n_messages, n_fixed):
-    n = parents[0].shape[0]
-    ids = jnp.arange(n, dtype=jnp.int32)
-    t0 = jnp.arange(n_messages) * rate_s
-    root0 = meta[0][0]
-
-    def one(seed):
-        base = jax.random.key(seed)
-        strag = _straggler_mask(base, ids < n_fixed, straggler_frac)
-        total = None
-        for parent, depth, (root, height, slot) in zip(parents, depths,
-                                                       meta):
-            fwd, link = _fwd_link_planes(base, slot, n_messages, n, strag)
-            fp = fwd_at_parent(parent, fwd, root)
-            t = level_sweep_xla(parent, depth, fp, link,
-                                t0.astype(fwd.dtype),
-                                root=root, height=height)
-            total = t if total is None else jnp.fmin(total, t)
-        with jax.named_scope("ldt_reduce"):
-            valid = (ids != root0)[None, :] & ~jnp.isnan(total)
-            sub = total - t0[:, None].astype(total.dtype)
-            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-            rel = valid.sum(axis=1) / (n - 1)
-            return ldt.mean(), rel.mean()
-
-    return jax.vmap(one)(seeds)
+    times, t0_rows = _stable_sweeps(
+        seeds, parents, depths, rate_s, straggler_frac, meta=meta,
+        n_messages=n_messages, n_fixed=n_fixed)
+    return _stable_reduce(times, t0_rows, meta[0][0], seeds.shape[0],
+                          n_messages)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("meta", "n_messages", "n_fixed"))
 def _stable_stats_hier(seeds, parents, depths, scales, rate_s,
                        straggler_frac, *, meta, n_messages, n_fixed):
-    """The :func:`_stable_stats` body with a per-node tier-scale multiply
-    fused after the threefry link generation — a separate jitted entry so
-    the flat sweep keeps its compiled program and cache untouched."""
-    n = parents[0].shape[0]
-    ids = jnp.arange(n, dtype=jnp.int32)
-    t0 = jnp.arange(n_messages) * rate_s
-    root0 = meta[0][0]
-
-    def one(seed):
-        base = jax.random.key(seed)
-        strag = _straggler_mask(base, ids < n_fixed, straggler_frac)
-        total = None
-        for parent, depth, scale, (root, height, slot) in zip(
-                parents, depths, scales, meta):
-            fwd, link = _fwd_link_planes(base, slot, n_messages, n, strag)
-            link = link * scale[None, :]
-            fp = fwd_at_parent(parent, fwd, root)
-            t = level_sweep_xla(parent, depth, fp, link,
-                                t0.astype(fwd.dtype),
-                                root=root, height=height)
-            total = t if total is None else jnp.fmin(total, t)
-        with jax.named_scope("ldt_reduce"):
-            valid = (ids != root0)[None, :] & ~jnp.isnan(total)
-            sub = total - t0[:, None].astype(total.dtype)
-            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-            rel = valid.sum(axis=1) / (n - 1)
-            return ldt.mean(), rel.mean()
-
-    return jax.vmap(one)(seeds)
+    """:func:`_stable_stats` with each plan's link plane multiplied by
+    its per-node tier scale after the threefry generation."""
+    times, t0_rows = _stable_sweeps(
+        seeds, parents, depths, rate_s, straggler_frac, meta=meta,
+        n_messages=n_messages, n_fixed=n_fixed,
+        adjust=lambda i, base, slot, link: link * scales[i][None, :])
+    return _stable_reduce(times, t0_rows, meta[0][0], seeds.shape[0],
+                          n_messages)
 
 
 def stable_stats_device(plans: Sequence[TreePlan], seeds: Sequence[int],
@@ -210,18 +267,24 @@ def stable_stats_device(plans: Sequence[TreePlan], seeds: Sequence[int],
         kw = dict(meta=_plan_meta(plans), n_messages=int(n_messages),
                   n_fixed=int(host[1][0].shape[0]))
     program = _stable_stats if hier is None else _stable_stats_hier
-    return _run_program(program, host, kw)
+    return _run_program(program, host, kw,
+                        cols=len(host[0]) * int(n_messages))
 
 
-def _run_program(program, host, static):
+def _run_program(program, host, static, *, cols):
     """One device program on host arrays, each step a span of its own:
     upload, dispatch (the jitted call until it returns: a compile on a
-    cache miss shows here) and the pull of the result to the host."""
+    cache miss shows here) and the pull of the result to the host.
+    ``cols`` is the seed × message columns of the program's node-major
+    sweep: the dispatch records the padded row width and its used
+    share."""
     leaves = jax.tree.leaves(host)
     with span("snow.device.upload",
               bytes=int(sum(np.asarray(a).nbytes for a in leaves))):
         args = jax.tree.map(jnp.asarray, host)
-    with span("snow.device.dispatch", program=program.__name__):
+    width = _row_width(cols)
+    with span("snow.device.dispatch", program=program.__name__,
+              row_width=width, row_fill=cols / width):
         out = program(*args, **static)
     with span("snow.device.pull"):
         return jax.tree.map(np.asarray, out)
@@ -233,42 +296,31 @@ def _run_program(program, host, static):
 def _stable_stats_loss(seeds, parents, depths, rate_s, straggler_frac,
                        loss_rate, loss_timeout, *, meta, n_messages,
                        n_fixed, max_attempts):
-    n = parents[0].shape[0]
-    ids = jnp.arange(n, dtype=jnp.int32)
-    t0 = jnp.arange(n_messages) * rate_s
-    root0 = meta[0][0]
+    def lossy(i, base, slot, link):
+        extra, lost = _loss_planes(base, slot, n_messages, link.shape[1],
+                                   loss_rate, loss_timeout, max_attempts)
+        return jnp.where(lost, jnp.nan, link + extra)
 
-    def one(seed):
-        base = jax.random.key(seed)
-        strag = _straggler_mask(base, ids < n_fixed, straggler_frac)
-        total = None
-        receipts = None
-        for parent, depth, (root, height, slot) in zip(parents, depths,
-                                                       meta):
-            fwd, link = _fwd_link_planes(base, slot, n_messages, n, strag)
-            extra, lost = _loss_planes(base, slot, n_messages, n,
-                                       loss_rate, loss_timeout,
-                                       max_attempts)
-            link = jnp.where(lost, jnp.nan, link + extra)
-            fp = fwd_at_parent(parent, fwd, root)
-            t = level_sweep_xla(parent, depth, fp, link,
-                                t0.astype(fwd.dtype),
-                                root=root, height=height)
-            r = (~jnp.isnan(t)) & (depth >= 1)[None, :]
-            receipts = r.astype(jnp.int32) if receipts is None \
-                else receipts + r
-            total = t if total is None else jnp.fmin(total, t)
-        with jax.named_scope("ldt_reduce"):
-            valid = (ids != root0)[None, :] & ~jnp.isnan(total)
-            sub = total - t0[:, None].astype(total.dtype)
-            got = valid.any(axis=1)
-            ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-            ldt_mean = (jnp.where(got, ldt, 0.0).sum()
-                        / jnp.maximum(got.sum(), 1))
-            rel = valid.sum(axis=1) / (n - 1)
-            return ldt_mean, rel.mean(), receipts.sum(axis=1).mean()
-
-    return jax.vmap(one)(seeds)
+    times, t0_rows = _stable_sweeps(
+        seeds, parents, depths, rate_s, straggler_frac, meta=meta,
+        n_messages=n_messages, n_fixed=n_fixed, adjust=lossy)
+    s, root0 = seeds.shape[0], meta[0][0]
+    receipts = sum(((~jnp.isnan(t)) & (depth >= 1)[:, None])
+                   .astype(jnp.int32) for t, depth in zip(times, depths))
+    total = functools.reduce(jnp.fmin, times)
+    with jax.named_scope("ldt_reduce"):
+        n = total.shape[0]
+        valid = ((jnp.arange(n, dtype=jnp.int32) != root0)[:, None]
+                 & ~jnp.isnan(total))
+        got = _per_seed(valid.any(axis=0), s, n_messages)
+        ldt = _per_seed(jnp.max(jnp.where(valid, total - t0_rows, -jnp.inf),
+                                axis=0), s, n_messages)
+        ldt_mean = (_sum_last(jnp.where(got, ldt, 0.0))
+                    / jnp.maximum(got.sum(axis=1), 1))
+        rel = _per_seed(valid.sum(axis=0) / (n - 1), s, n_messages)
+        rec = _per_seed(receipts.sum(axis=0), s, n_messages)
+        return (ldt_mean, _sum_last(rel) / n_messages,
+                _sum_last(rec.astype(jnp.float32)) / n_messages)
 
 
 def stable_stats_device_loss(plans: Sequence[TreePlan],
@@ -290,7 +342,8 @@ def stable_stats_device_loss(plans: Sequence[TreePlan],
         kw = dict(meta=_plan_meta(plans), n_messages=int(n_messages),
                   n_fixed=int(host[1][0].shape[0]),
                   max_attempts=int(loss.max_attempts))
-    return _run_program(_stable_stats_loss, host, kw)
+    return _run_program(_stable_stats_loss, host, kw,
+                        cols=len(host[0]) * int(n_messages))
 
 
 @functools.partial(jax.jit,
@@ -339,58 +392,74 @@ def stable_times_device(plans: Sequence[TreePlan], seed: int,
 
 
 # ------------------------------------------------------------------ #
-# Churn traces: lax.map over padded epochs inside the seed vmap       #
+# Churn traces: lax.map over padded epochs, all seeds in each sweep   #
 # ------------------------------------------------------------------ #
+@jax.named_scope("epoch_gather")
+def _epoch_window(bank, slot, rows, col0, q, s):
+    """``(P, S·q)`` window of a node-major ``(slots, n_bank, S·M)`` bank:
+    the epoch's member rows of one slot, then each seed's ``q`` messages
+    from ``col0``, those past the last message clipped to it (the edge
+    padding)."""
+    w = bank[slot, rows]                                   # row gather
+    p = w.shape[0]
+    w = jnp.pad(w.reshape(p, s, -1), ((0, 0), (0, 0), (0, q - 1)),
+                mode="edge")
+    return lax.dynamic_slice_in_dim(w, col0, q, axis=2).reshape(p, s * q)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("q", "height", "maxp", "n_slots",
                                     "m_total"))
 def _trace_ldt(seeds, st, fixed_mask, *, q, height, maxp, n_slots,
                m_total):
     n_bank = fixed_mask.shape[0]
+    s = seeds.shape[0]
+    width = _row_width(s * q)
 
-    def one(seed):
+    def draws(seed):
         base = jax.random.key(seed)
         strag = _straggler_mask(base, fixed_mask)
-        planes = [_fwd_link_planes(base, s, m_total, n_bank, strag)
-                  for s in range(n_slots)]
-        # XLA fuses the draws into the stacking: scoped with them
-        with jax.named_scope("delay_planes"):
-            fwd_all = jnp.stack([p[0] for p in planes])   # (S, M, n_bank)
-            link_all = jnp.stack([p[1] for p in planes])
+        planes = [_fwd_link_planes(base, sl, m_total, n_bank, strag)
+                  for sl in range(n_slots)]
+        return ([p[0] for p in planes], [p[1] for p in planes])
 
-        def ep_fn(e):
-            cols = jnp.clip(e["col0"] + jnp.arange(q, dtype=jnp.int32),
-                            0, m_total - 1)
-            p0 = e["parent"][0].shape[0]
-            total = jnp.full((q, p0), jnp.nan, dtype=jnp.float32)
-            for p in range(maxp):
-                sl = e["slot"][p]
-                with jax.named_scope("epoch_gather"):
-                    fwd = jnp.take(jnp.take(fwd_all, sl, axis=0)[cols],
-                                   e["rows"], axis=-1)        # (q, P)
-                    link = jnp.take(jnp.take(link_all, sl, axis=0)[cols],
-                                    e["rows"], axis=-1)
-                parent = e["parent"][p]
-                fp = fwd_at_parent(parent, fwd, e["root"])
-                t = level_sweep_xla(parent, e["depth"][p], fp, link,
-                                    e["times"].astype(fwd.dtype),
-                                    root=e["root"], height=height)
-                total = jnp.fmin(total, jnp.where(e["mask"][p], t,
-                                                  jnp.nan))
-            with jax.named_scope("ldt_reduce"):
-                sub = total - e["times"][:, None].astype(total.dtype)
-                valid = e["sel"][None, :] & ~jnp.isnan(total)
-                ldt = jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1)
-                ok = e["msgmask"] & valid.any(axis=1)
-                return jnp.where(ok, ldt, 0.0).sum(), ok.sum()
+    fwd, link = jax.vmap(draws)(seeds)
+    # XLA fuses the draws into the node-major stacking: scoped with them
+    with jax.named_scope("delay_planes"):
+        def bank(planes):                     # (slots, n_bank, S·M)
+            return jnp.stack([jnp.transpose(x, (2, 0, 1))
+                              .reshape(n_bank, s * m_total)
+                              for x in planes])
+        fwd_all, link_all = bank(fwd), bank(link)
 
-        sums, cnts = lax.map(ep_fn, st)
+    def ep_fn(e):
+        times = e["times"].astype(jnp.float32)
+        t0_rows = _pad_cols(jnp.tile(times, s), width, jnp.nan)
+        total = None
+        for p in range(maxp):
+            sl = e["slot"][p]
+            fwd = _epoch_window(fwd_all, sl, e["rows"], e["col0"], q, s)
+            link = _epoch_window(link_all, sl, e["rows"], e["col0"], q, s)
+            t = _sweep_rows(e["parent"][p], e["depth"][p],
+                            _pad_cols(fwd, width, 0.0),
+                            _pad_cols(link, width, jnp.nan), t0_rows,
+                            root=e["root"], height=height)
+            t = jnp.where(e["mask"][p][:, None], t, jnp.nan)
+            total = t if total is None else jnp.fmin(total, t)
         with jax.named_scope("ldt_reduce"):
-            c = cnts.sum()
-            return jnp.where(c > 0, sums.sum() / jnp.maximum(c, 1),
-                             jnp.nan)
+            valid = e["sel"][:, None] & ~jnp.isnan(total)
+            ldt = _per_seed(jnp.max(jnp.where(valid, total - t0_rows,
+                                              -jnp.inf), axis=0), s, q)
+            ok = e["msgmask"][None, :] & _per_seed(valid.any(axis=0), s, q)
+            return ldt, ok
 
-    return jax.vmap(one)(seeds)
+    ldt, ok = lax.map(ep_fn, st)                          # (E, S, q)
+    with jax.named_scope("ldt_reduce"):
+        # each epoch's sum over its messages, then the sum over epochs
+        sums = _sum_last(jnp.moveaxis(_sum_last(jnp.where(ok, ldt, 0.0)),
+                                      0, -1))
+        c = ok.sum(axis=(0, 2))
+        return jnp.where(c > 0, sums / jnp.maximum(c, 1), jnp.nan)
 
 
 def _stack_epochs(epochs) -> Tuple[dict, int, int, int]:
@@ -457,7 +526,8 @@ def trace_ldt_device(epochs, trace, seeds: Sequence[int]) -> np.ndarray:
     reduction needs the delays."""
     with span("snow.device.pack"):
         args, static = trace_ldt_args(epochs, trace, seeds)
-    return _run_program(_trace_ldt, args, static)
+    return _run_program(_trace_ldt, args, static,
+                        cols=len(args[0]) * static["q"])
 
 
 # ------------------------------------------------------------------ #

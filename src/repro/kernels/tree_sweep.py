@@ -6,8 +6,16 @@ applied level by level down a :class:`~repro.core.planner.TreePlan`.
 This module is the device expression of that sweep, shared by the
 device-resident sweep engine (``repro.core.device_sweep``):
 
-* :func:`level_sweep_xla` — the jitted reference: a ``lax.fori_loop``
-  over levels, each step one fused gather-add-where over all n nodes.
+* :func:`level_sweep_rows` (with :func:`fwd_at_parent_rows`) — the
+  node-major fast path that the multi-seed programs run: planes are
+  ``(n, B)``, all seeds × messages of one plan in a node's row (``B``
+  padded to a multiple of 128 lanes), so a level's ``t[parent]``
+  gathers whole contiguous rows.  Bit-equal to the reference on the
+  transposed planes.
+* :func:`level_sweep_xla` — the reference: a ``lax.fori_loop`` over
+  levels, each step one fused gather-add-where over all n nodes of
+  ``(..., n)`` planes, gathering single lanes of the minor axis.  The
+  single-seed entries and the Pallas kernel's bit-equality tests use it.
 * :func:`tree_sweep_pallas` — the Pallas kernel, following the
   ``flash_attention.py`` tiling idiom: grid = (message blocks, level);
   the level axis is the trailing (sequential) grid dimension, so the
@@ -68,6 +76,40 @@ def level_sweep_xla(parent: jax.Array, depth: jax.Array, fp: jax.Array,
     def body(h, t):
         cand = (jnp.take(t, parent, axis=-1) + fp) + link
         return jnp.where(depth == h, cand, t)
+
+    return lax.fori_loop(1, height + 1, body, t)
+
+
+@jax.named_scope("level_sweep")
+def fwd_at_parent_rows(parent: jax.Array, fwd: jax.Array,
+                       root: int) -> jax.Array:
+    """:func:`fwd_at_parent` on a node-major ``(n, B)`` plane: each node
+    gathers its parent's whole row."""
+    return jnp.where((parent == root)[:, None], 0.0,
+                     jnp.take(fwd, parent, axis=0))
+
+
+@jax.named_scope("level_sweep")
+def level_sweep_rows(parent: jax.Array, depth: jax.Array, fp: jax.Array,
+                     link: jax.Array, t0: jax.Array, *, root: int,
+                     height: int) -> jax.Array:
+    """(n, B) absolute first-delivery times, node-major fast path.
+
+    :func:`level_sweep_xla` with the node axis leading: ``fp``/``link``
+    are ``(n, B)``, every column an independent sweep (all seeds ×
+    messages of one plan), ``t0`` is ``(B,)``.  A level's ``t[parent]``
+    gathers whole contiguous rows instead of single lanes; the float
+    program is the same, so the result is bit-equal to
+    :func:`level_sweep_xla` on the transposed planes.
+    """
+    t = jnp.full(jnp.broadcast_shapes(fp.shape, link.shape), jnp.nan,
+                 dtype=fp.dtype)
+    t = t.at[root].set(t0)
+    at_level = depth[:, None]
+
+    def body(h, t):
+        cand = (jnp.take(t, parent, axis=0) + fp) + link
+        return jnp.where(at_level == h, cand, t)
 
     return lax.fori_loop(1, height + 1, body, t)
 

@@ -261,3 +261,232 @@ def test_experiments_device_engine_routing():
     g = Cell(protocol="gossip", scene="stable", n=200, k=4, payload=64,
              view_model="oracle", engine="device")
     assert route(spec, g).startswith("skipped:")
+
+
+# ------------------------------------------------------------------ #
+# (d) node-major sweep: bit-equal to the per-seed lane formulation    #
+# ------------------------------------------------------------------ #
+def _random_plan(rng, n, n_pad):
+    """A random recursive tree over ``n`` shuffled labels, root at label
+    0, plus ``n_pad`` padded members (``depth = -1``, parent 0)."""
+    order = np.concatenate([[0], 1 + rng.permutation(n - 1)])
+    parent = np.zeros(n + n_pad, dtype=np.int32)
+    depth = np.full(n + n_pad, -1, dtype=np.int32)
+    depth[0] = 0
+    for i in range(1, n):
+        p = order[rng.integers(0, i)]
+        parent[order[i]] = p
+        depth[order[i]] = depth[p] + 1
+    return parent, depth
+
+
+@pytest.mark.parametrize("width", [100, 128, 200])
+@pytest.mark.parametrize("kind", ["random", "planner"])
+def test_level_sweep_rows_bit_equal_lane_sweep(kind, width):
+    """``level_sweep_rows``/``fwd_at_parent_rows`` on an ``(n, B)`` plane
+    equal ``level_sweep_xla``/``fwd_at_parent`` on its transpose, bit for
+    bit, NaNs included: padded members, NaN links, any row width."""
+    import jax.numpy as jnp
+
+    from repro.kernels.tree_sweep import (fwd_at_parent, fwd_at_parent_rows,
+                                          level_sweep_rows, level_sweep_xla)
+
+    rng = np.random.default_rng(width)
+    if kind == "random":
+        parent, depth = _random_plan(rng, 900, 37)
+        root = 0
+    else:
+        plan = stable_plans("coloring", np.arange(900), 0, 4)[1]
+        parent = np.concatenate([np.asarray(plan.parent, np.int32),
+                                 np.zeros(37, np.int32)])
+        depth = np.concatenate([np.asarray(plan.depth, np.int32),
+                                np.full(37, -1, np.int32)])
+        root = int(plan.root)
+    n = parent.shape[0]
+    height = int(depth.max())
+    fwd = rng.uniform(0.01, 0.2, (n, width)).astype(np.float32)
+    link = rng.lognormal(np.log(4e-4), 0.35, (n, width)).astype(np.float32)
+    link[rng.random((n, width)) < 0.02] = np.nan
+    t0 = rng.uniform(0.0, 20.0, width).astype(np.float32)
+    p, d = jnp.asarray(parent), jnp.asarray(depth)
+
+    fp_rows = fwd_at_parent_rows(p, jnp.asarray(fwd), root)
+    fp_lane = fwd_at_parent(p, jnp.asarray(fwd.T), root)
+    assert np.array_equal(np.asarray(fp_rows), np.asarray(fp_lane).T,
+                          equal_nan=True)
+    rows = level_sweep_rows(p, d, fp_rows, jnp.asarray(link),
+                            jnp.asarray(t0), root=root, height=height)
+    lane = level_sweep_xla(p, d, fp_lane, jnp.asarray(link.T),
+                           jnp.asarray(t0), root=root, height=height)
+    rows, lane = np.asarray(rows), np.asarray(lane).T
+    assert np.array_equal(rows, lane, equal_nan=True)
+    assert np.isnan(rows[depth < 0]).all()
+    assert np.isfinite(rows[depth >= 0]).any()
+
+
+def _lane_stable_stats(seeds, parents, depths, rate_s, frac, *, meta,
+                       n_messages, n_fixed):
+    """The per-seed lane formulation of ``_stable_stats``: a seed ``vmap``
+    around ``(messages, n)`` sweeps, reduced per message, then the mean
+    over messages of each seed, summed left to right."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import device_sweep as ds
+    from repro.kernels.tree_sweep import fwd_at_parent, level_sweep_xla
+
+    @jax.jit
+    def per_message(seeds):
+        n = parents[0].shape[0]
+        ids = jnp.arange(n, dtype=jnp.int32)
+        t0 = jnp.arange(n_messages) * rate_s
+
+        def one(seed):
+            base = jax.random.key(seed)
+            strag = ds._straggler_mask(base, ids < n_fixed, frac)
+            total = None
+            for parent, depth, (root, height, slot) in zip(parents, depths,
+                                                           meta):
+                fwd, link = ds._fwd_link_planes(base, slot, n_messages, n,
+                                                strag)
+                t = level_sweep_xla(parent, depth,
+                                    fwd_at_parent(parent, fwd, root), link,
+                                    t0.astype(fwd.dtype), root=root,
+                                    height=height)
+                total = t if total is None else jnp.fmin(total, t)
+            valid = (ids != meta[0][0])[None, :] & ~jnp.isnan(total)
+            sub = total - t0[:, None].astype(total.dtype)
+            return (jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1),
+                    valid.sum(axis=1) / (n - 1))
+
+        return jax.vmap(one)(seeds)
+
+    ldt, rel = per_message(seeds)
+    return _mean_left_to_right(ldt), _mean_left_to_right(rel)
+
+
+def _mean_left_to_right(x):
+    """Row means of an ``(S, M)`` array, each summed left to right."""
+    import jax
+
+    def mean(x):
+        total = x[:, 0]
+        for j in range(1, x.shape[1]):
+            total = total + x[:, j]
+        return total / x.shape[1]
+
+    return jax.jit(mean)(x)
+
+
+@pytest.mark.parametrize("protocol", ["coloring", "snow"])
+def test_stable_stats_bit_equal_lane_formulation(protocol):
+    import jax.numpy as jnp
+
+    from repro.core import device_sweep as ds
+
+    n = 5000
+    plans = stable_plans(protocol, np.arange(n), 0, 4)
+    args = (jnp.asarray(np.arange(40, 45, dtype=np.uint32)),
+            tuple(jnp.asarray(np.asarray(p.parent, np.int32))
+                  for p in plans),
+            tuple(jnp.asarray(np.asarray(p.depth, np.int32))
+                  for p in plans),
+            jnp.float32(1.0), jnp.float32(ds.STRAGGLER_FRAC))
+    kw = dict(meta=ds._plan_meta(plans), n_messages=20, n_fixed=n)
+    got = ds._stable_stats(*args, **kw)
+    want = _lane_stable_stats(*args, **kw)
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
+def _lane_trace_ldt(seeds, st, fixed_mask, *, q, height, maxp, n_slots,
+                    m_total):
+    """The per-seed lane formulation of ``_trace_ldt``: ``lax.map`` over
+    epochs inside a seed ``vmap``, each epoch's ``(q, P)`` window
+    gathered lane by lane; per-message LDTs, then each seed's mean,
+    summed left to right over each epoch's messages, then over epochs."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.core import device_sweep as ds
+    from repro.kernels.tree_sweep import fwd_at_parent, level_sweep_xla
+
+    @jax.jit
+    def per_message(seeds, st, fixed_mask):
+        n_bank = fixed_mask.shape[0]
+
+        def one(seed):
+            base = jax.random.key(seed)
+            strag = ds._straggler_mask(base, fixed_mask)
+            planes = [ds._fwd_link_planes(base, s, m_total, n_bank, strag)
+                      for s in range(n_slots)]
+            fwd_all = jnp.stack([p[0] for p in planes])
+            link_all = jnp.stack([p[1] for p in planes])
+
+            def ep_fn(e):
+                cols = jnp.clip(e["col0"] + jnp.arange(q, dtype=jnp.int32),
+                                0, m_total - 1)
+                total = jnp.full((q, e["parent"][0].shape[0]), jnp.nan,
+                                 dtype=jnp.float32)
+                for p in range(maxp):
+                    sl = e["slot"][p]
+                    fwd = jnp.take(jnp.take(fwd_all, sl, axis=0)[cols],
+                                   e["rows"], axis=-1)
+                    link = jnp.take(jnp.take(link_all, sl, axis=0)[cols],
+                                    e["rows"], axis=-1)
+                    parent = e["parent"][p]
+                    t = level_sweep_xla(
+                        parent, e["depth"][p],
+                        fwd_at_parent(parent, fwd, e["root"]), link,
+                        e["times"].astype(fwd.dtype), root=e["root"],
+                        height=height)
+                    total = jnp.fmin(total, jnp.where(e["mask"][p], t,
+                                                      jnp.nan))
+                sub = total - e["times"][:, None].astype(total.dtype)
+                valid = e["sel"][None, :] & ~jnp.isnan(total)
+                return (jnp.max(jnp.where(valid, sub, -jnp.inf), axis=1),
+                        e["msgmask"] & valid.any(axis=1))
+
+            return lax.map(ep_fn, st)
+
+        ldt, ok = jax.vmap(one)(seeds)                 # (S, E, q)
+        return jnp.swapaxes(ldt, 0, 1), jnp.swapaxes(ok, 0, 1)
+
+    ldt, ok = per_message(seeds, st, fixed_mask)
+
+    @jax.jit
+    def mean(ldt, ok):
+        x = jnp.where(ok, ldt, 0.0)
+        sums = None
+        for e in range(x.shape[0]):           # epochs, in order
+            epoch = x[e, :, 0]
+            for j in range(1, x.shape[2]):    # its messages, in order
+                epoch = epoch + x[e, :, j]
+            sums = epoch if sums is None else sums + epoch
+        c = ok.sum(axis=(0, 2))
+        return jnp.where(c > 0, sums / jnp.maximum(c, 1), jnp.nan)
+
+    return mean(ldt, ok)
+
+
+@pytest.mark.parametrize("protocol", ["coloring", "snow"])
+def test_trace_ldt_bit_equal_lane_formulation(protocol):
+    """A breakdown trace at n = 5,000 with 5 seeds: the node-major
+    ``_trace_ldt`` equals the lane formulation bit for bit, including
+    the window's clipping past the last message."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import device_sweep as ds
+
+    trace = paper_breakdown_trace(5000, 20, 1.0, 7, 10, detect_after=2.5)
+    epochs = compile_trace(protocol, trace, 4, trace.all_ids())
+    args, static = ds.trace_ldt_args(epochs, trace, range(5))
+    st = args[1]
+    assert (st["col0"] + static["q"] > static["m_total"]).any()
+    args = jax.tree.map(jnp.asarray, args)
+    got = ds._trace_ldt(*args, **static)
+    want = _lane_trace_ldt(*args, **static)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.array_equal(np.asarray(got), np.asarray(want))

@@ -19,7 +19,7 @@ ARGS = {
     "snow.sweep": {"engine"},
     "snow.device.pack": set(),
     "snow.device.upload": {"bytes"},
-    "snow.device.dispatch": {"program"},
+    "snow.device.dispatch": {"program", "row_width", "row_fill"},
     "snow.device.pull": set(),
 }
 #: spans that the row accounting and the trace scans write more than once
@@ -92,8 +92,15 @@ def test_a_query_writes_its_spans_inside_its_query_span(
     assert by_name["snow.plan.trees"]["epochs"] >= by_name[
         "snow.plan.trees"]["full"]
     assert by_name["snow.device.upload"]["bytes"] > 0
-    assert by_name["snow.device.dispatch"]["program"] == (
+    dispatch = by_name["snow.device.dispatch"]
+    assert dispatch["program"] == (
         "_trace_ldt" if scene == "breakdown" else "_stable_stats")
+    # the node-major sweep's row: 3 seeds × the messages of an epoch
+    # (all 6 in a stable sweep), padded to 128 lanes
+    assert dispatch["row_width"] == 128
+    cols = dispatch["row_fill"] * 128
+    assert cols == round(cols) and round(cols) % 3 == 0
+    assert (cols == 18) is (scene == "stable")
     assert by_name["snow.sweep"]["engine"] == "device"
 
 

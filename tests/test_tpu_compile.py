@@ -136,8 +136,9 @@ def _entry_fusions(compiled, shape):
 
 def test_trace_ldt_ops_carry_their_scopes_on_the_chip(one_chip):
     """On the v5e compiler the named scopes survive fusion: the threefry
-    draws fuse into the stacking of the delay planes, whose op carries
-    ``delay_planes``, and each stage's scope reaches the compiled ops."""
+    draws fuse into the node-major stacking of the delay planes, whose op
+    carries ``delay_planes``, and each stage's scope reaches the compiled
+    ops."""
     n = 20_000
     trace = paper_churn_trace(n, 20)
     epochs = compile_trace("snow", trace, 4, trace.all_ids(), 64)
@@ -147,8 +148,10 @@ def test_trace_ldt_ops_carry_their_scopes_on_the_chip(one_chip):
                          jax.dtypes.canonicalize_dtype(a.dtype)), args)
     compiled = ds._trace_ldt.lower(*shapes, **static).compile()
     n_bank = shapes[2].shape[0]
-    planes = _entry_fusions(
-        compiled, f"f32[{SEEDS},{static['n_slots']},20,{n_bank}]")
+    planes = [name for shape in (f"f32[{n_bank},{SEEDS},20]",
+                                 f"f32[{static['n_slots']},{n_bank},"
+                                 f"{SEEDS * 20}]")
+              for name in _entry_fusions(compiled, shape)]
     assert planes and all("delay_planes" in name for name in planes)
     text = compiled.as_text()
     assert all(f"/{scope}/" in text or f"({scope})/" in text
@@ -163,3 +166,26 @@ def test_stable_stats_ops_carry_their_scopes_on_the_chip(one_chip):
     text = compiled.as_text()
     for scope in ("level_sweep", "delay_planes", "ldt_reduce"):
         assert f"/{scope}/" in text or f"({scope})/" in text, scope
+
+
+#: HBM bytes one level pass may move at n = 1M: the node-major pass
+#: (take + add + add + where over ``(1M, 128)``) counts 7.2 GB on the v5e
+#: compiler; the lane layout ``(5, 20, 1M)`` counts 64.4 GB, and an
+#: unpadded row of 55 lanes 67 GB
+LEVEL_PASS_BYTES = 12e9
+
+
+def test_level_sweep_rows_pass_gathers_whole_rows(one_chip):
+    from repro.kernels.tree_sweep import level_sweep_rows
+
+    def one_pass(parent, depth, fp, link, t0):
+        return level_sweep_rows(parent, depth, fp, link, t0, root=0,
+                                height=1)
+
+    idx = _shape(one_chip, (N,), jnp.int32)
+    plane = _shape(one_chip, (N, 128), jnp.float32)
+    compiled = jax.jit(one_pass).lower(
+        idx, idx, plane, plane,
+        _shape(one_chip, (128,), jnp.float32)).compile()
+    moved = compiled.cost_analysis()["bytes accessed"]
+    assert 0 < moved <= LEVEL_PASS_BYTES, moved
