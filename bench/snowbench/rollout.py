@@ -13,8 +13,10 @@ collective-permutes that takes minutes to compile.)
 
 Work is counted in bytes received: (chips − 1) × the tree's bytes per
 rollout.  The check regenerates the reader's bytes on every chip from
-the seed and counts the elements of the last rollout's output that
-differ from them.
+the seed and counts the elements that differ from them in two rollouts'
+outputs: the last one, and one drawn from the seed among all the
+window's rollouts (a reservoir of one, kept on the chips until the
+check: one more tree's bytes a chip).
 """
 from __future__ import annotations
 
@@ -115,7 +117,8 @@ class Generator:
                                   devices=self.devices)
         self.shapes = param_shapes(cfg)
         self.reader = int(cfg["reader"])
-        self.params = self.out = None
+        self.params = self.out = self.sample = None
+        self.draw = np.random.default_rng(seed32(seed))
         self.failed: List[str] = []
 
     def _per_chip(self, body, in_specs, out_specs):
@@ -171,6 +174,8 @@ class Generator:
 
         self.out = None
         self.out = jax.block_until_ready(self._rollout())
+        if self.draw.random() * (index + 1) < 1.0:
+            self.sample = self.out  # each rollout kept with chance 1/(index+1)
         return float((len(self.devices) - 1) * tree_bytes(self.cfg))
 
     def e2e(self, work: float, seconds: float) -> Dict[str, float]:
@@ -181,13 +186,14 @@ class Generator:
         yield
 
     def release(self) -> None:
-        """Free the input trees; the last rollout's output stays for the
-        check."""
+        """Free the input trees; the last rollout's output and the drawn
+        one stay for the check."""
         self.params = None
 
-    def elements_off(self) -> np.ndarray:
-        """Per chip, per group of leaves of one shape: how many elements of the last rollout's
-        output differ from the reader's bytes regenerated from the seed."""
+    def elements_off(self, outs: List[dict]) -> np.ndarray:
+        """Per rollout output in ``outs``, per chip, per group of leaves of
+        one shape: how many elements differ from the reader's bytes
+        regenerated from the seed."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
@@ -205,11 +211,14 @@ class Generator:
                 counts.append(jnp.sum(got != want, dtype=jnp.int32))
             return jnp.stack(counts)[None, :]
 
-        return np.asarray(self._per_chip(body, (P(), P()), P(AXIS))(
-            self.out, self._key_data()))
+        count = self._per_chip(body, (P(), P()), P(AXIS))
+        kd = self._key_data()
+        return np.stack([np.asarray(count(out, kd)) for out in outs])
 
     def check(self) -> Tuple[Dict[str, float], int]:
         if self.out is None:
             return {}, len(self.failed)
-        return ({"elements_off": float(self.elements_off().sum())},
+        outs = [self.out] + ([self.sample] if self.sample is not self.out
+                             else [])
+        return ({"elements_off": float(self.elements_off(outs).sum())},
                 len(self.failed))

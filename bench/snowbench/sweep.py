@@ -9,10 +9,18 @@ traffic file's protocol and scene, with delay seeds drawn fresh from
 ``fresh_trace_seed``, a fresh breakdown trace seed, so new victims).
 
 Work is counted as deliveries: seeds × messages × n per query.
+
+The host's allocator is held to one policy for the whole run
+(:func:`keep_freed_memory`): a query's planning allocates and frees
+hundreds of megabytes of numpy arrays, and glibc's default policy hands
+some of it back to the kernel, to fault it in again page by page, in
+some queries and not in others, by the heap's history.
 """
 from __future__ import annotations
 
 import contextlib
+import ctypes
+import ctypes.util
 import math
 from typing import Dict, List, Tuple
 
@@ -37,6 +45,27 @@ def query(seed: int, index: int, count: int, traffic: dict) -> dict:
     return q
 
 
+#: glibc's ``mallopt`` parameters (malloc.h)
+M_TRIM_THRESHOLD, M_MMAP_THRESHOLD = -1, -3
+
+
+def keep_freed_memory() -> dict:
+    """Fix glibc's two thresholds for handing freed memory back: blocks
+    under 1 GiB come from the heap, not from a mapping of their own
+    (by default the cut-off moves with the sizes freed so far), and the
+    heap's free top is kept up to 2 GiB.  A query then reuses the pages
+    of the last query's arrays instead of faulting them in anew.
+    Returns what ``mallopt`` answered (1: taken), or nothing where the C
+    library has no ``mallopt``."""
+    name = ctypes.util.find_library("c")
+    libc = ctypes.CDLL(name) if name else None
+    if libc is None or not hasattr(libc, "mallopt"):
+        return {}
+    return {"M_MMAP_THRESHOLD": libc.mallopt(M_MMAP_THRESHOLD, 1 << 30),
+            "M_TRIM_THRESHOLD": libc.mallopt(M_TRIM_THRESHOLD,
+                                             2**31 - 1)}
+
+
 class Generator:
     """Drives ``run_cell`` for one cell, keeps every row, and checks one
     query drawn from the seed against :mod:`snowbench.reference`."""
@@ -48,6 +77,7 @@ class Generator:
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
         self.done: List[Tuple[dict, dict]] = []
         self.failed: List[str] = []
+        print(f"allocator: mallopt {keep_freed_memory()}", flush=True)
 
     def _spec(self, q: dict):
         from repro.core.experiments import ExperimentSpec

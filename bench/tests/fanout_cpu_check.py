@@ -1,8 +1,8 @@
 """The fan-out cell at a tiny size on four virtual CPU devices: a sound
-run, the float8 control and the faults its check must catch.  Run as a
-script (the device count is fixed before JAX starts); prints one JSON
-object: for each case, whether the run came out correct and the elements
-that differed."""
+run, the float8 control and the faults its check must catch, one of them
+in the rollouts before the last.  Run as a script (the device count is
+fixed before JAX starts); prints one JSON object: for each case, whether
+the run came out correct and the elements that differed."""
 import json
 import os
 import sys
@@ -75,6 +75,23 @@ def main() -> None:
     import calibrate
 
     out["control"] = calibrate.rollout_control(gen)
+
+    # an answer altered in every rollout but the last: only the rollout
+    # drawn from the seed can show it
+    gen = harness.generator(bench.traffic("ckpt-rollout"))(
+        bench.config("rwkv6-tiny"), bench.traffic("ckpt-rollout"), 11,
+        devices)
+    gen.setup()
+    sound_rollout = gen.rollout
+    gen.rollout = jax.jit(lambda x: altered(x, gen.mesh, rollout.AXIS,
+                                            root=gen.reader, k=gen.cfg["k"]))
+    for i in range(5):
+        gen.step(i)
+    gen.rollout = sound_rollout
+    gen.step(5)
+    gen.release()
+    out["earlier_fault"] = {"drawn_last": gen.sample is gen.out,
+                            **gen.check()[0]}
     out["traced"] = sorted(harness.run(bench, "tiny-fanout", 3, 0.3, True,
                                        devices))
     print(json.dumps(out))

@@ -1,7 +1,7 @@
 """The checkpoint fan-out cell on four virtual CPU devices: a sound run is
 correct; the float8 control and every fault the cell can have (the
 exchange left out, half of the leaves left out, an answer altered on one
-chip) come out incorrect."""
+chip, in the last rollout or only before it) come out incorrect."""
 import json
 import os
 import subprocess
@@ -34,6 +34,13 @@ def test_sound_fanout_is_correct(cases):
 def test_fault_is_caught(cases, fault):
     assert cases[fault]["correct"] is False
     assert cases[fault]["elements_off"] > 0
+
+
+def test_fault_before_the_last_rollout_is_caught(cases):
+    """The check also compares a rollout drawn from the seed, so a fault
+    that spares the last rollout is caught."""
+    assert cases["earlier_fault"]["drawn_last"] is False
+    assert cases["earlier_fault"]["elements_off"] > 0
 
 
 def test_float8_control_fails_the_exact_limit(cases):

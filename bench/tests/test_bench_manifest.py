@@ -4,7 +4,6 @@ import json
 
 import pytest
 
-import tinybench
 from tinybench import REPO
 from snowbench.manifest import NAME, UNIT, Bench, problems
 
@@ -60,16 +59,16 @@ def test_sweep_cells_report_their_metrics():
 
 
 def test_pending_cell_joins_the_manifest_with_no_problems():
-    """The fan-out cell, built but not yet measured on four chips, joins
-    the manifest as new entries with no problem."""
-    doc = copy.deepcopy(DOC)
-    for group, entries in tinybench.fanout_entries().items():
-        doc[group].extend(entries)
-    assert problems(doc, REPO) == []
-    assert sum(w["chips"] == 4 for w in doc["workloads"]) == 1
-    bench = Bench(REPO, REPO / "bench", doc)
+    """The fan-out cell, once pending, is in the manifest: the one cell on
+    four chips, reporting ``fanout_GBps`` and ``setup_s``, its bytes
+    compared exactly."""
+    assert [w["name"] for w in DOC["workloads"] if w["chips"] == 4] == [
+        "ckpt-fanout-4chip"]
+    bench = Bench.load(REPO)
     assert {m["name"] for m in bench.end_to_end("ckpt-fanout-4chip")} == {
         "fanout_GBps", "setup_s"}
+    assert {m["name"] for m in bench.per_layer("ckpt-fanout-4chip")} == {
+        "collective_ms_per_rollout", "device_idle_share.fanout"}
     assert bench.limits("ckpt-fanout-4chip") == {"elements_off": 0.0}
 
 

@@ -48,6 +48,16 @@ def test_query_seeds_are_a_function_of_seed_and_index():
     assert a != sweep.query(2**33 + 5, 4, 5, t)
     assert a != sweep.query(2**33 + 6, 3, 5, t)
     assert all(0 <= s < 2**31 for s in a["seeds"] + (a["trace_seed"],))
+
+
+def test_the_allocator_policy_is_taken_where_glibc_runs():
+    import platform
+
+    if platform.libc_ver()[0] != "glibc":
+        assert sweep.keep_freed_memory() == {}
+        return
+    assert sweep.keep_freed_memory() == {"M_MMAP_THRESHOLD": 1,
+                                         "M_TRIM_THRESHOLD": 1}
     assert "trace_seed" not in sweep.query(7, 0, 5, {})
 
 

@@ -1,11 +1,9 @@
 """A copy of the benchmark with tiny cells added as new files, for CPU tests.
 
-``make(tmp)`` copies ``BENCHMARK.json`` and ``bench/`` into ``tmp``, joins
-the manifest entries of the fan-out cell (``data/ckpt-fanout-4chip.entries.json``,
-built but not yet measured on four chips), and adds, as new files and new
-manifest entries only, one small Snow fleet configuration, one small
-RWKV-6 tree, a cell for each traffic mix and the limits of the matching
-full-size cell.  Nothing existing is edited.
+``make(tmp)`` copies ``BENCHMARK.json`` and ``bench/`` into ``tmp`` and
+adds, as new files and new manifest entries only, one small Snow fleet
+configuration, one small RWKV-6 tree, a cell for each traffic mix and the
+limits of the matching full-size cell.  Nothing existing is edited.
 """
 from __future__ import annotations
 
@@ -51,20 +49,12 @@ def tiny_configs() -> dict:
     return {"snow-tiny": snow, "rwkv6-tiny": rwkv}
 
 
-def fanout_entries() -> dict:
-    """The fan-out cell's manifest entries, by group."""
-    return json.loads((BENCH / "tests/data/ckpt-fanout-4chip.entries.json")
-                      .read_text())
-
-
 def make(tmp: Path) -> Path:
     """The copy, with the tiny cells added; returns its root."""
     tmp = Path(tmp)
     shutil.copytree(BENCH, tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     doc = json.loads((REPO / "BENCHMARK.json").read_text())
-    for group, entries in fanout_entries().items():
-        doc[group].extend(entries)
     for name, cfg in tiny_configs().items():
         path = f"bench/configs/{name}.json"
         (tmp / path).write_text(json.dumps(cfg))
